@@ -22,6 +22,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Iterable, Optional
 
+import jax
+
 from repro.cluster.balancer import BalancerConfig, KVBalancer
 from repro.cluster.recovery import RecoveryConfig, RecoveryManager
 from repro.cluster.router import ClusterDevice, ClusterRouter, RouterConfig
@@ -139,12 +141,19 @@ class ClusterSpec:
         balancer/recovery instances from the spec's configs. Runtime
         instances passed here override the spec's declarative configs;
         a bare ``faults`` injector implies a default recovery manager
-        (injected faults without a watchdog would hang the stream)."""
+        (injected faults without a watchdog would hang the stream).
+
+        Each replica group takes the next ``devices`` of the host's JAX
+        devices in order, so on a host with enough devices no two groups
+        share one. A spec with more physical devices than the host has
+        wraps around: the fleet is then simulated on shared devices."""
         from repro.perfmodel.model import PAM_LLAMA_7B
         model_desc = self.model_desc or PAM_LLAMA_7B
         scfg = self.serving
+        local = jax.devices()
         devices: list[ClusterDevice] = []
         counts: dict[str, int] = {}
+        first = 0
         for grp in self.groups:
             dc, g = grp.cls, grp.devices
             idx = counts.get(dc.name, 0)
@@ -161,9 +170,14 @@ class ClusterSpec:
                                            pool_blocks=pool)
             lat = (None if self.wallclock
                    else make_device_latency_model(gdc, model_desc))
+            if g > len(local):
+                raise ValueError(f"replica group {name} needs {g} "
+                                 f"devices; the host has {len(local)}")
+            owned = [local[(first + j) % len(local)] for j in range(g)]
+            first += g
             eng = EngineSpec(model=self.model, serving=dev_scfg,
                              shard=g, name=name).build(
-                                 params, latency_model=lat)
+                                 params, latency_model=lat, devices=owned)
             prior = (step_time_prior(gdc, model_desc)
                      if not self.wallclock else 0.0)
             ppt = (float(lat({"prefill_tokens": 1, "active": 0}))

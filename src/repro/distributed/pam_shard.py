@@ -24,27 +24,16 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from repro import compat  # noqa: F401  (backfills jax.shard_map on 0.4)
-
 from jax.sharding import Mesh, PartitionSpec as P
 
 
 @functools.lru_cache(maxsize=None)
-def decode_mesh(shard: int, *, axis: str = "model") -> Mesh:
-    """The serving engine's 1-D decode mesh over the first ``shard``
-    local XLA devices. Cached so every same-shard engine (and every
-    replica group of the same size) shares ONE mesh object — which is
-    what lets their jitted dispatches share the module-level compile
-    caches."""
-    devs = jax.devices()
-    if len(devs) < shard:
-        raise ValueError(
-            f"shard={shard} needs {shard} local XLA devices but only "
-            f"{len(devs)} present; on CPU relaunch under "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count="
-            f"{max(shard, 8)} (must be set before jax is imported)")
-    return Mesh(np.asarray(devs[:shard]), (axis,))
+def decode_mesh(devices: tuple, *, axis: str = "model") -> Mesh:
+    """The serving engine's 1-D decode mesh over ``devices`` (a tuple of
+    local JAX devices). Cached so every engine on the same devices
+    shares ONE mesh object — which is what lets their jitted dispatches
+    share the module-level compile caches."""
+    return Mesh(np.asarray(devices), (axis,))
 
 
 def merge_collective_bytes(n_layers: int, n_heads: int, head_dim: int,
@@ -141,8 +130,7 @@ def fused_update_decode(q, k_cache, v_cache, k_new, v_new, kv_lens, *,
     k_new/v_new: (B, Hkv, dh); kv_lens: (B,) pre-append lengths.
     Returns (out, mass, k_cache, v_cache).
     """
-    from repro.models import perf_flags
-    mesh = perf_flags.abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     B = q.shape[0]
     dp: tuple | None = tuple(a for a in mesh.axis_names
                              if a in ("pod", "data")) or None
@@ -217,7 +205,7 @@ def fused_update_decode(q, k_cache, v_cache, k_new, v_new, kv_lens, *,
 
 
 def make_sharded_paged_decode_attn(mesh: Mesh, hot_mask, paged_mask,
-                                   block_table, block_live, *,
+                                   block_table, *,
                                    axis: str = "model", scale=None):
     """The PR 10 tentpole attention: hot-ring ⊕ paged partials with the
     ring's SLOT axis and the pool's BLOCK axis sharded over ``axis``.
@@ -259,8 +247,7 @@ def make_sharded_paged_decode_attn(mesh: Mesh, hot_mask, paged_mask,
                                             ring_position_map)
     nshards = mesh.shape[axis]
 
-    def local_fn(q, kc, vc, pk, pv, bt, bl, hot_mask, paged_mask,
-                 kv_lens):
+    def local_fn(q, kc, vc, pk, pv, bt, hot_mask, paged_mask, kv_lens):
         B, H, d = q.shape
         Hkv, W_loc = kc.shape[1], kc.shape[2]
         NB_loc, bs = pk.shape[0], pk.shape[1]
@@ -282,7 +269,7 @@ def make_sharded_paged_decode_attn(mesh: Mesh, hot_mask, paged_mask,
         # ---- paged partial over MY physical blocks --------------------
         lo = r * NB_loc
         part_pgd = ops.paged_decode_attention_partial(
-            q, pk, pv, bt, pgd, block_live=bl, block_offset=lo, scale=sc)
+            q, pk, pv, bt, pgd, block_offset=lo, scale=sc)
         merged = osm.merge_partials(part, part_pgd)
 
         # ---- cross-shard reduction (Alg. 1 across devices) ------------
@@ -327,14 +314,14 @@ def make_sharded_paged_decode_attn(mesh: Mesh, hot_mask, paged_mask,
     sharded = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), P(None, None, axis, None), P(None, None, axis, None),
-                  P(axis), P(axis), P(), P(), P(), P(), P()),
+                  P(axis), P(axis), P(), P(), P(), P()),
         out_specs=(P(), P()),
         check_vma=False,
     )
 
     def decode_attn_fn(q, k_cache, v_cache, pk, pv, kv_lens):
         return sharded(q, k_cache, v_cache, pk, pv, block_table,
-                       block_live, hot_mask, paged_mask, kv_lens)
+                       hot_mask, paged_mask, kv_lens)
 
     return decode_attn_fn
 

@@ -18,9 +18,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-
-from repro import compat  # noqa: F401  (backfills jax.shard_map on 0.4)
-
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat  # noqa: F401  (backfills pltpu.CompilerParams on 0.4)
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128

@@ -3,8 +3,10 @@
 ``pam_decode_attention`` is the full Alg. 1 pipeline: per-tier local stage
 (flash_decode kernel over that tier's pool) followed by the hierarchical
 reduction — intra-device merge over splits, inter-tier merge over tiers.
-Wrappers fall back to interpret mode automatically off-TPU so the same call
-sites run in tests, examples, and on hardware.
+On the TPU every kernel compiles with Mosaic; interpret mode is never
+chosen there. Off the TPU the serving entry points take their jnp reference
+branch, and a caller that forces a kernel (``use_kernel=True``, as the
+kernel tests do) gets it in interpret mode.
 """
 
 from __future__ import annotations
@@ -38,18 +40,6 @@ def fused_attention(q, k, v, *, causal=True, scale=None, block_q=128,
                             interpret=interpret)
 
 
-def merge_decode(o: jax.Array, m: jax.Array, l: jax.Array,
-                 out_dtype=None) -> jax.Array:
-    """Reduction stage (Alg. 1 ``Reduction``): merge split partials.
-
-    o: (B, H, nsplit, d); m/l: (B, H, nsplit). Returns (B, H, d).
-    """
-    part = osm.AttnPartial(o=jnp.moveaxis(o, 2, 0), m=jnp.moveaxis(m, 2, 0),
-                           l=jnp.moveaxis(l, 2, 0))
-    merged = osm.merge_many(part)
-    return osm.finalize(merged, out_dtype=out_dtype)
-
-
 @functools.partial(jax.jit, static_argnames=("kv_len", "scale", "block_s",
                                              "interpret"))
 def decode_attention(q, k, v, mask=None, *, kv_len=None, kv_lens=None,
@@ -64,7 +54,7 @@ def decode_attention(q, k, v, mask=None, *, kv_len=None, kv_lens=None,
     o, m, l = _flash_decode(q, k, v, mask, kv_len=kv_len, kv_lens=kv_lens,
                             scale=scale, block_s=block_s,
                             interpret=interpret)
-    return merge_decode(o, m, l, out_dtype=q.dtype)
+    return osm.finalize(osm.AttnPartial(o, m, l), out_dtype=q.dtype)
 
 
 def decode_attention_partial(q, k, v, mask=None, *, kv_len=None,
@@ -72,15 +62,20 @@ def decode_attention_partial(q, k, v, mask=None, *, kv_len=None,
                              interpret=None) -> osm.AttnPartial:
     """Local stage only — returns the merged per-pool partial (for the
     inter-tier / inter-device reduction). Shapes as ``decode_attention``;
-    partial fields are (B, H, d) / (B, H)."""
+    partial fields are (B, H, d) / (B, H), with ``m == -inf`` on a row
+    that has no live token (the merge identity)."""
     if interpret is None:
         interpret = not _on_tpu()
     o, m, l = _flash_decode(q, k, v, mask, kv_len=kv_len, kv_lens=kv_lens,
                             scale=scale, block_s=block_s,
                             interpret=interpret)
-    part = osm.AttnPartial(o=jnp.moveaxis(o, 2, 0), m=jnp.moveaxis(m, 2, 0),
-                           l=jnp.moveaxis(l, 2, 0))
-    return osm.merge_many(part)
+    return _kernel_partial(o, m, l)
+
+
+def _kernel_partial(o, m, l) -> osm.AttnPartial:
+    """A kernel's (o, m, l) as an ``AttnPartial``: the kernels' finite
+    NEG_INF max of an empty row becomes the algebra's -inf identity."""
+    return osm.AttnPartial(o=o, m=jnp.where(l > 0, m, -jnp.inf), l=l)
 
 
 def masked_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -172,7 +167,6 @@ def paged_decode_attention_partial(q: jax.Array, k_pool: jax.Array,
                                    v_pool: jax.Array,
                                    block_table: jax.Array,
                                    token_mask: jax.Array, *,
-                                   block_live: jax.Array | None = None,
                                    block_offset=None,
                                    scale=None, use_kernel: bool | None = None,
                                    interpret: bool | None = None
@@ -200,21 +194,15 @@ def paged_decode_attention_partial(q: jax.Array, k_pool: jax.Array,
         inside = ((block_table >= block_offset)
                   & (block_table < block_offset + nb_local))
         token_mask = token_mask & jnp.repeat(inside, bs, axis=1)
-        live = inside if block_live is None else (block_live & inside)
-        block_live = live
         block_table = jnp.where(inside, block_table - block_offset, 0)
     if use_kernel is None:
         use_kernel = _on_tpu()
     if use_kernel:
         if interpret is None:
             interpret = not _on_tpu()
-        o, m, l = _flash_decode_paged(q, k_pool, v_pool, block_table,
-                                      token_mask, block_live=block_live,
-                                      scale=scale, interpret=interpret)
-        part = osm.AttnPartial(o=jnp.moveaxis(o, 2, 0),
-                               m=jnp.moveaxis(m, 2, 0),
-                               l=jnp.moveaxis(l, 2, 0))
-        return osm.merge_many(part)
+        return _kernel_partial(*_flash_decode_paged(
+            q, k_pool, v_pool, block_table, token_mask, scale=scale,
+            interpret=interpret))
     from repro.core.pam_interface import paged_gather_logical
     d = q.shape[-1]
     sc = scale if scale is not None else 1.0 / (d ** 0.5)
@@ -229,7 +217,6 @@ def paged_masked_decode_attention(q: jax.Array, k_cache: jax.Array,
                                   v_pool: jax.Array, block_table: jax.Array,
                                   hot_mask: jax.Array, paged_mask: jax.Array,
                                   kv_lens: jax.Array, *,
-                                  block_live: jax.Array | None = None,
                                   scale=None, use_kernel: bool | None = None
                                   ) -> tuple[jax.Array, jax.Array]:
     """Tiered decode attention: hot-ring partial ⊕ paged warm/cold partial.
@@ -295,8 +282,7 @@ def paged_masked_decode_attention(q: jax.Array, k_cache: jax.Array,
     s_pool = _grouped_scores(q, gk, sc)                # (B, Hkv, rep, Smax)
     if use_kernel:
         part_paged = paged_decode_attention_partial(
-            q, k_pool, v_pool, block_table, pgd, block_live=block_live,
-            scale=sc, use_kernel=True)
+            q, k_pool, v_pool, block_table, pgd, scale=sc, use_kernel=True)
     else:
         gv = paged_gather_logical(v_pool, block_table)
         part_paged = _grouped_partial_from_scores(s_pool, gv, pgd)

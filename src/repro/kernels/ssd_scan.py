@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat  # noqa: F401  (backfills pltpu.CompilerParams on 0.4)
 
 DEFAULT_CHUNK = 128
 

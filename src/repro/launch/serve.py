@@ -363,4 +363,6 @@ def _serve_mode(args, ap, cfg, params, scfg) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

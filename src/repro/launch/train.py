@@ -66,7 +66,8 @@ def main(argv=None):
     # multi-device: shard params/opt over available devices
     n_dev = jax.device_count()
     if n_dev > 1:
-        mesh = jax.make_mesh((1, n_dev), ("data", "model"))
+        mesh = jax.make_mesh((1, n_dev), ("data", "model"), axis_types=(
+            jax.sharding.AxisType.Auto,) * 2)
         pspecs = shd.param_specs(cfg, mesh)
         ospecs = shd.opt_state_specs(cfg, mesh)
 

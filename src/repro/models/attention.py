@@ -120,12 +120,11 @@ def sp_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     gather instead of multi-GB score/activation reshards. q: (B,S,H,dk),
     k/v: (B,S,Hkv,d*)."""
     from jax.sharding import PartitionSpec as P
-    from repro.models import perf_flags
     B, S, H, dh = q.shape
     Hkv, dv = k.shape[2], v.shape[-1]
     rep = H // Hkv
     scale = 1.0 / math.sqrt(dh)
-    mesh = perf_flags.abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if "model" in mesh.axis_names:
         dp = tuple(a for a in mesh.axis_names
                    if a in ("pod", "data")) or None

@@ -99,7 +99,7 @@ def moe_forward(p: MoEParams, x: jax.Array, cfg: MoEConfig
         if not perf_flags.enabled("moe_pin"):
             return t
         import jax.sharding as jsh
-        mesh = perf_flags.abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         if not ("data" in mesh.axis_names and "model" in mesh.axis_names):
             return t
         ok = all(ax is None or t.shape[i] % mesh.shape[ax] == 0

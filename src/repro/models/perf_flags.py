@@ -37,8 +37,3 @@ def enabled(name: str) -> bool:
 
 def active() -> tuple[str, ...]:
     return tuple(sorted(_FLAGS))
-
-def abstract_mesh():
-    """Ambient mesh across jax versions (see ``repro.compat``)."""
-    from repro import compat
-    return compat.abstract_mesh()

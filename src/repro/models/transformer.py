@@ -145,7 +145,7 @@ def _sp_pin(h: jax.Array) -> jax.Array:
         return h
     from jax.sharding import PartitionSpec as P
     try:
-        mesh = perf_flags.abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         dp = tuple(a for a in mesh.axis_names if a in ("pod", "data"))
         return jax.lax.with_sharding_constraint(
             h, P(dp or None, "model", None))

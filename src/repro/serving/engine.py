@@ -318,10 +318,9 @@ def _fused_decode_body(cfg: ModelConfig, pcfg: Optional[PAMManagerConfig],
             # sharded step is bit-identical to the unsharded one
             from repro.distributed import pam_shard as psh
             d_fn = psh.make_sharded_paged_decode_attn(
-                mesh, hot_m, pgd_m, bt_eff, block_live)
+                mesh, hot_m, pgd_m, bt_eff)
         else:
-            d_fn = pm.make_paged_decode_attn(hot_m, pgd_m, bt_eff,
-                                             block_live)
+            d_fn = pm.make_paged_decode_attn(hot_m, pgd_m, bt_eff)
         # append coordinates for the new token (same for every layer);
         # inactive rows write the sentinel trash page
         pos = cache.lengths
@@ -749,7 +748,8 @@ class ServingEngine:
     def __init__(self, spec, params=None, scfg: Optional[ServingConfig]
                  = None,
                  latency_model: Optional[Callable[[dict], float]] = None,
-                 name: Optional[str] = None):
+                 name: Optional[str] = None,
+                 devices: Optional[tuple] = None):
         # canonical construction is EngineSpec.build(params) — the spec
         # carries model + serving config + shard + name declaratively.
         # The legacy (cfg, params, scfg, ...) positional signature still
@@ -781,6 +781,12 @@ class ServingEngine:
         self.latency_model = latency_model
         self.name = spec.name                  # cluster device handle
         self.shard = spec.shard
+        if devices is not None and len(devices) != spec.shard:
+            raise ValueError(f"shard={spec.shard} engine given "
+                             f"{len(devices)} devices")
+        # the devices this engine's params, cache and state live on
+        # (None: JAX's default device, or the first `shard` devices)
+        self.devices = None if devices is None else tuple(devices)
         self.mesh = None                       # set when spec.shard > 1
         self.cache_shardings = None
         self.clock = 0.0                       # simulated seconds
@@ -840,7 +846,17 @@ class ServingEngine:
             spec.validate()
             from repro.distributed import pam_shard as psh
             from repro.distributed import sharding as shd
-            self.mesh = psh.decode_mesh(spec.shard)
+            if self.devices is None:
+                local = jax.devices()
+                if len(local) < spec.shard:
+                    raise ValueError(
+                        f"shard={spec.shard} needs {spec.shard} local "
+                        f"XLA devices but only {len(local)} present; on "
+                        f"CPU relaunch under XLA_FLAGS=--xla_force_host_"
+                        f"platform_device_count={max(spec.shard, 8)} "
+                        f"(must be set before jax is imported)")
+                self.devices = tuple(local[:spec.shard])
+            self.mesh = psh.decode_mesh(self.devices)
             self.params = jax.device_put(
                 params, shd.param_shardings(cfg, self.mesh))
             self.cache_shardings = shd.serving_cache_shardings(
@@ -850,6 +866,13 @@ class ServingEngine:
             self.pam_state = jax.device_put(
                 self.pam_state, jax.tree.map(lambda _: rep,
                                              self.pam_state))
+        elif self.devices is not None:
+            # a one-device replica commits its state to its own device;
+            # every dispatch then runs where its operands live
+            dev = self.devices[0]
+            self.params = jax.device_put(params, dev)
+            self.cache = jax.device_put(self.cache, dev)
+            self.pam_state = jax.device_put(self.pam_state, dev)
 
         self.trie: Optional[PrefixTrie] = None
         if scfg.prefix_cache:
@@ -888,6 +911,9 @@ class ServingEngine:
         if self.mesh is not None:
             self.tokens_dev = jax.device_put(
                 self.tokens_dev, self.cache_shardings.lengths)
+        elif self.devices is not None:
+            self.tokens_dev = jax.device_put(self.tokens_dev,
+                                             self.devices[0])
         # per-slot request ids: the sampling-key operand of the fused
         # dispatch (keys derive as fold_in(fold_in(seed, rid), position),
         # so no PRNG state survives between dispatches)
@@ -1053,6 +1079,15 @@ class ServingEngine:
                 self.scfg.eos_token, self.hot_window,
                 self.scfg.sample_seed, self.mesh, self.cache_shardings)
         return self._micro_jits[k]
+
+    def lower_decode_step(self, k: int = 1):
+        """The fused ``k``-step decode dispatch lowered for this engine's
+        current operands (a ``jax.stages.Lowered``: ``.compile()`` gives
+        the program the device runs, ``.as_text()`` its HLO)."""
+        B = self.scfg.max_batch
+        return self._get_micro(k).lower(
+            self.params, self.tokens_dev, self.cache, self.pam_state,
+            jnp.zeros((B,), bool), jnp.asarray(self.rids_host))
 
     def _admit_commit_dispatch(self, cache, pam_state, tokens_dev, sub,
                                logits, slots, lengths, rids,

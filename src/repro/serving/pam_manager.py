@@ -101,16 +101,15 @@ def make_masked_decode_attn(participate: jax.Array):
 
 
 def make_paged_decode_attn(hot_mask: jax.Array, paged_mask: jax.Array,
-                           block_table: jax.Array, block_live: jax.Array):
+                           block_table: jax.Array):
     """Paged decode-attn factory for the block-table fast path.
 
     ``hot_mask``/``paged_mask``: (B, Smax) — the participation set split
     by tier residency (hot reads stay on the dense kernel-ready cache;
     warm/cold reads gather the shared pool through ``block_table``).
     ``block_table``: (B, nb) physical ids with dead logical blocks
-    already remapped onto the sentinel; ``block_live``: (B, nb) which
-    blocks hold at least one participating warm/cold token — the pages
-    the gather actually touches.
+    already remapped onto the sentinel. The pages read are the blocks
+    holding at least one ``paged_mask`` token.
 
     The produced function matches the paged ``decode_attn_fn`` contract
     of ``attention_decode``: ``d_fn(q, kc, vc, pk, pv, kv_lens)`` ->
@@ -120,7 +119,7 @@ def make_paged_decode_attn(hot_mask: jax.Array, paged_mask: jax.Array,
         from repro.kernels import ops as kops
         return kops.paged_masked_decode_attention(
             q, k_cache, v_cache, pk, pv, block_table, hot_mask,
-            paged_mask, kv_lens, block_live=block_live)
+            paged_mask, kv_lens)
 
     return d_fn
 

@@ -22,7 +22,7 @@ internally.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Sequence
 
 from repro.models.config import ModelConfig
 from repro.serving.engine import ServingConfig, ServingEngine
@@ -83,7 +83,14 @@ class EngineSpec:
         return nb + 1
 
     def build(self, params: Any, *,
-              latency_model: Optional[Callable[[dict], float]] = None
-              ) -> ServingEngine:
-        """Materialize the engine (the canonical constructor path)."""
-        return ServingEngine(self, params, latency_model=latency_model)
+              latency_model: Optional[Callable[[dict], float]] = None,
+              devices: Optional[Sequence[Any]] = None) -> ServingEngine:
+        """Materialize the engine (the canonical constructor path).
+
+        ``devices`` — exactly ``shard`` local JAX devices — pins the
+        engine's params, cache and state to them; by default a one-device
+        engine uses JAX's default device and a sharded one the first
+        ``shard`` devices."""
+        return ServingEngine(self, params, latency_model=latency_model,
+                             devices=None if devices is None
+                             else tuple(devices))

@@ -17,11 +17,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
 jax.config.update("jax_platform_name", "cpu")
 
-from repro import compat  # noqa: E402,F401  (backfills jax.set_mesh on 0.4)
 
 from repro.distributed.pam_shard import (  # noqa: E402
     make_gather_based_decode_attn, make_sequence_sharded_decode_attn)
@@ -36,8 +35,15 @@ from repro.checkpoint import save_pytree, restore_pytree  # noqa: E402
 assert jax.device_count() == 8, jax.device_count()
 
 
+def _auto_mesh(shape, names):
+    """A mesh whose axes GSPMD partitions on its own (``jax.make_mesh``
+    makes Explicit axes by default)."""
+    return jax.make_mesh(shape, names,
+                         axis_types=(AxisType.Auto,) * len(names))
+
+
 def check_pam_shard_map():
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = _auto_mesh((8,), ("model",))
     key = jax.random.PRNGKey(0)
     B, H, Hkv, S, dh = 2, 8, 4, 64, 16
     q = jax.random.normal(jax.random.fold_in(key, 0), (B, H, dh))
@@ -73,7 +79,7 @@ def check_fused_update_decode():
     """§Perf pam_shard_decode path: masked local cache write + psum merge
     == unsharded scatter + dense attention."""
     from repro.distributed.pam_shard import fused_update_decode
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = _auto_mesh((8,), ("model",))
     key = jax.random.PRNGKey(4)
     B, H, Hkv, S, dh = 2, 8, 4, 64, 16
     q = jax.random.normal(jax.random.fold_in(key, 0), (B, H, dh))
@@ -106,7 +112,7 @@ def check_sharded_train_step():
     from repro.training import optim
     cfg = reduced(get_config("qwen3-0.6b"))
     tcfg = TrainConfig(adamw=optim.AdamWConfig(lr=1e-3))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = _auto_mesh((2, 4), ("data", "model"))
 
     state = init_train_state(cfg, tcfg, jax.random.PRNGKey(0))
     key = jax.random.PRNGKey(1)
@@ -150,7 +156,7 @@ def check_sharded_train_step():
 
 
 def check_pipeline():
-    mesh = jax.make_mesh((8,), ("stage",))
+    mesh = _auto_mesh((8,), ("stage",))
     L, d = 8, 16
     key = jax.random.PRNGKey(3)
     ws = jax.random.normal(key, (L, d, d)) * 0.3
@@ -184,7 +190,7 @@ def check_pipeline():
 def check_elastic_restore(tmpdir="/tmp/elastic_ck"):
     cfg = reduced(get_config("qwen3-0.6b"))
     params = tf.init_params(cfg, jax.random.PRNGKey(7))
-    mesh_a = jax.make_mesh((2, 4), ("data", "model"))
+    mesh_a = _auto_mesh((2, 4), ("data", "model"))
     specs = shd.param_specs(cfg, mesh_a)
     params_a = jax.tree.map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh_a, s)),

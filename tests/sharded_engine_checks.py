@@ -17,10 +17,14 @@ Checks:
   4. mid-decode migration shard 2 -> shard 4 stays bit-exact (sampled)
   5. replica group: 2-way group param bytes <= 0.6x the full copy,
      cluster streams exact; from_cli round-trip forms the ISSUE's
-     "hbm:1,cxl:2 --shard 2" topology
+     "hbm:1,cxl:2 --shard 2" topology; each group owns its devices
+  6. ``chip_smoke.py --chips 4``'s body at reduced widths: four bf16
+     one-device replicas on four devices and the float32 shard=4 engine,
+     each token-identical to its one-device twin
 """
 import dataclasses
 import os
+import sys
 
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                            + os.environ.get("XLA_FLAGS", ""))
@@ -163,9 +167,32 @@ def check_replica_groups():
                                 shard=2)
     assert [g.devices for g in spec.groups] == [1, 2]
     assert spec.cli() == "hbm:1,cxl:2"       # round-trip
+    # each replica group owns its own devices, in order
+    held = [[d.id for d in dev.engine.devices]
+            for dev in spec.build(PARAMS).devices]
+    assert held == [[0], [1, 2]], held
     print(f"5. 2-way replica group: {eng.params_bytes_per_device()} "
           f"bytes/device vs {full_bytes} full copy; cluster streams "
           f"exact; hbm:1,cxl:2 --shard 2 forms [1, 2]-device groups")
+
+
+def check_chip_smoke_fleet():
+    import importlib.util
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chip_smoke.py")
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    smoke = importlib.util.module_from_spec(spec)
+    sys.modules["chip_smoke"] = smoke
+    spec.loader.exec_module(smoke)
+    cfg = dataclasses.replace(CFG, dtype="bfloat16")
+    shape = smoke.SmokeShape(requests=4, prompt_len=24, new_tokens=6,
+                             max_len=64, block_size=8, hot_window=16)
+    out = smoke.run_fleet(cfg, jax.devices()[:4], shape, seed=1)
+    assert sorted(i for g in out["replicas"].values() for i in g) == [
+        0, 1, 2, 3], out["replicas"]
+    assert out["shard"] == [0, 1, 2, 3]
+    print("6. chip_smoke --chips 4 body: replicas on devices 0-3 and "
+          "shard=4 token-identical to their one-device twins")
 
 
 if __name__ == "__main__":
@@ -174,4 +201,5 @@ if __name__ == "__main__":
     check_micro_twins()
     check_cross_shard_migration()
     check_replica_groups()
+    check_chip_smoke_fleet()
     print("ALL SHARDED ENGINE CHECKS PASSED")

@@ -121,6 +121,31 @@ def test_flash_decode_ragged_kv_lens():
                                rtol=1e-4, atol=1e-4)
 
 
+def test_flash_decode_dead_row_is_merge_identity():
+    """The split walk carries (m, l, acc) across splits in-kernel; a row
+    with no live token comes out as the merge identity (m=-inf, l=0,
+    o=0), exactly as the jnp local-attention partial."""
+    from repro.core import online_softmax as osm
+    key = jax.random.PRNGKey(23)
+    B, H, Hkv, S, d = 2, 4, 2, 96, 16
+    q = jax.random.normal(jax.random.fold_in(key, 0), (B, H, d))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (B, Hkv, S, d))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (B, Hkv, S, d))
+    mask = (jax.random.uniform(jax.random.fold_in(key, 3), (B, S)) < 0.5
+            ).at[1].set(False)
+    got = ops.decode_attention_partial(q, k, v, mask, block_s=32,
+                                       interpret=True)
+    want = osm.local_attention(
+        q.reshape(B, Hkv, H // Hkv, d), k[:, :, None], v[:, :, None],
+        mask=mask[:, None, None, :])
+    want = osm.AttnPartial(want.o.reshape(B, H, d), want.m.reshape(B, H),
+                           want.l.reshape(B, H))
+    assert np.isneginf(np.asarray(got.m[1])).all()
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
 def test_masked_decode_attention_kernel_equals_einsum():
     """ops.masked_decode_attention: Pallas-kernel path (interpret) and the
     grouped-einsum fallback agree on output AND per-token mass."""

@@ -33,10 +33,11 @@ def _rand_pool(key, NB, bs, Hkv, d):
 
 
 @pytest.mark.parametrize("rep", [1, 4])
-@pytest.mark.parametrize("bs", [8, 16])
+@pytest.mark.parametrize("bs", [8, 16, 48])     # 48: two mask words
 def test_paged_kernel_matches_reference_gather(rep, bs):
-    """flash_decode_paged (interpret mode, block table walked in-grid)
-    equals the jnp gather-through-table reference partial."""
+    """flash_decode_paged (interpret mode, block table walked in-grid,
+    all kv heads of a block per grid cell, mask as scalar-prefetched bit
+    words) equals the jnp gather-through-table reference partial."""
     B, Hkv, d, NB, nb = 3, 2, 16, 12, 4
     H = Hkv * rep
     key = jax.random.PRNGKey(rep * 31 + bs)
@@ -53,6 +54,36 @@ def test_paged_kernel_matches_reference_gather(rep, bs):
     for a, b in zip(got, ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_kernel_dead_rows_and_shard_offset(dtype):
+    """A row with no participating token is the merge identity (m=-inf,
+    l=0, o=0) on the kernel path as on the reference; with
+    ``block_offset`` both read only the local block range; bf16 pools
+    agree at the fp32 accumulation tolerance."""
+    B, Hkv, rep, d, NB, nb, bs = 3, 2, 2, 16, 12, 4, 16
+    key = jax.random.PRNGKey(5)
+    q = jax.random.normal(jax.random.fold_in(key, 0), (B, Hkv * rep, d),
+                          dtype)
+    pk, pv = (x.astype(dtype) for x in _rand_pool(key, NB, bs, Hkv, d))
+    bt = jax.random.randint(jax.random.fold_in(key, 3), (B, nb), 0, NB)
+    mask = jax.random.uniform(jax.random.fold_in(key, 4),
+                              (B, nb * bs)) < 0.5
+    mask = mask.at[1].set(False)
+    for offset in (None, 4):
+        local = (pk, pv) if offset is None else (pk[4:9], pv[4:9])
+        got = kops.paged_decode_attention_partial(
+            q, *local, bt, mask, block_offset=offset, use_kernel=True,
+            interpret=True)
+        ref = kops.paged_decode_attention_partial(
+            q, *local, bt, mask, block_offset=offset, use_kernel=False)
+        assert np.isneginf(np.asarray(got.m[1])).all()
+        assert not np.asarray(got.l[1]).any()
+        assert not np.asarray(got.o[1]).any()
+        for a, b in zip(got, ref):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-5)
 
 
 def _mirrored_pool(kc, vc, bs):
