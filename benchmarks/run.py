@@ -19,7 +19,7 @@ def main(argv=None) -> None:
     ap.add_argument("--section", default="all",
                     choices=["all", "figs", "kernels", "engine",
                              "roofline", "cluster", "chaos", "prefix",
-                             "serving", "obs", "shard"])
+                             "serving", "shard"])
     ap.add_argument("--dryrun-dir", default="experiments/dryrun")
     ap.add_argument("--out", default=None, metavar="BENCH.json",
                     help="write decode tokens/s + dispatch counts (and all "
@@ -72,11 +72,6 @@ def main(argv=None) -> None:
         from benchmarks.serving_bench import serving_rows
         serving, srows = serving_rows()
         rows += srows
-    obs = None
-    if args.section in ("all", "obs"):
-        from benchmarks.obs_bench import obs_rows
-        obs, orows = obs_rows()
-        rows += orows
     shard = None
     if args.section in ("all", "shard"):
         from benchmarks.shard_bench import shard_rows
@@ -125,15 +120,6 @@ def main(argv=None) -> None:
             payload["serving_tokens_lost"] = serving["tokens_lost_total"]
             payload["serving_chunked_p99_tpot_ratio"] = \
                 serving["chunked_prefill"]["p99_tpot_ratio"]
-        if obs is not None:
-            # telemetry-overhead trajectory point (PR 9): decode tok/s
-            # with collectors on vs off, streams pinned identical
-            payload["obs"] = obs
-            payload["obs_overhead_ratio"] = obs["overhead_ratio"]
-            payload["obs_decode_tok_s_enabled"] = \
-                obs["enabled"]["decode_tok_s"]
-            payload["obs_decode_tok_s_disabled"] = \
-                obs["disabled"]["decode_tok_s"]
         if shard is not None:
             # sharded-engine trajectory point (PR 10): twin-exact
             # streams at shard 1/2/4, one dispatch/step under

@@ -645,61 +645,62 @@ class ClusterRouter:
     def tick(self) -> bool:
         """One router iteration. Returns False when the stream is fully
         served (no arrivals, no queue, no running or suspended work)."""
-        if self.faults is not None:
-            for ev in self.faults.due(self.ticks):
-                self._apply_fault(ev)
-        # idle fleet + future arrivals: jump the fleet to the next event
-        if (self.arrivals and not self.queue and not self._steppable()
-                and not self._failed_pending()
-                and not (self.recovery and self.recovery.suspended)):
-            t = self.arrivals[0].arrival
-            for d in self._alive():
-                d.engine.clock = max(d.engine.clock, t)
-        self._release_arrivals()
-        self._dispatch()
-        if self.recovery is not None:
-            self._maybe_resume()
-            self._maybe_preempt()
-        steppable = self._steppable()
-        if steppable:
-            # event-driven: advance the furthest-behind steppable device
-            dev = min(steppable, key=lambda d: d.engine.clock)
-            dev.engine.step()
-            dev.steps += 1
+        with obs_trace.span("router.tick"):
+            if self.faults is not None:
+                for ev in self.faults.due(self.ticks):
+                    self._apply_fault(ev)
+            # idle fleet + future arrivals: jump the fleet to the next event
+            if (self.arrivals and not self.queue and not self._steppable()
+                    and not self._failed_pending()
+                    and not (self.recovery and self.recovery.suspended)):
+                t = self.arrivals[0].arrival
+                for d in self._alive():
+                    d.engine.clock = max(d.engine.clock, t)
+            self._release_arrivals()
+            self._dispatch()
             if self.recovery is not None:
-                self.recovery.observe_step(self.devices.index(dev), dev,
-                                           dev.engine.last_step_time)
-            self._collect(dev)
-        elif self._failed_pending() and self.recovery is not None:
-            # nothing steppable but a silent device still holds work:
-            # the watchdog WAITS — detection costs real simulated time
-            alive = self._alive()
-            pool = alive or self.devices
-            t = (max(max(d.engine.clock for d in pool), self._wait_clock)
-                 + self.recovery.cfg.heartbeat_timeout_s)
-            self._wait_clock = t
-            for d in alive:
-                d.engine.clock = max(d.engine.clock, t)
-        self.ticks += 1
-        if self._mreg.enabled:
-            self._m_ticks.inc()
-            self._m_queue.set(len(self.queue))
-        tr = obs_trace.COLLECTOR
-        if tr is not None:
-            tr.counter("router", "shared_queue", self.now(),
-                       depth=len(self.queue))
-        if self.recovery is not None:
-            self._watchdog()
-        if (self.balancer is not None
-                and self.ticks % self.balancer.cfg.rebalance_interval == 0):
-            # migrated requests carry their outputs with them; pending
-            # tokens surface at the destination's next _collect
-            self.balancer.rebalance(
-                [d for d in self._up() if not d.killed], self.ticks)
-            self._observe_balancer()
-        return bool(self.arrivals or self.queue or self._steppable()
-                    or self._failed_pending()
-                    or (self.recovery and self.recovery.suspended))
+                self._maybe_resume()
+                self._maybe_preempt()
+            steppable = self._steppable()
+            if steppable:
+                # event-driven: advance the furthest-behind steppable device
+                dev = min(steppable, key=lambda d: d.engine.clock)
+                dev.engine.step()
+                dev.steps += 1
+                if self.recovery is not None:
+                    self.recovery.observe_step(self.devices.index(dev), dev,
+                                               dev.engine.last_step_time)
+                self._collect(dev)
+            elif self._failed_pending() and self.recovery is not None:
+                # nothing steppable but a silent device still holds work:
+                # the watchdog WAITS — detection costs real simulated time
+                alive = self._alive()
+                pool = alive or self.devices
+                t = (max(max(d.engine.clock for d in pool), self._wait_clock)
+                     + self.recovery.cfg.heartbeat_timeout_s)
+                self._wait_clock = t
+                for d in alive:
+                    d.engine.clock = max(d.engine.clock, t)
+            self.ticks += 1
+            if self._mreg.enabled:
+                self._m_ticks.inc()
+                self._m_queue.set(len(self.queue))
+            tr = obs_trace.COLLECTOR
+            if tr is not None:
+                tr.counter("router", "shared_queue", self.now(),
+                           depth=len(self.queue))
+            if self.recovery is not None:
+                self._watchdog()
+            if (self.balancer is not None
+                    and self.ticks % self.balancer.cfg.rebalance_interval == 0):
+                # migrated requests carry their outputs with them; pending
+                # tokens surface at the destination's next _collect
+                self.balancer.rebalance(
+                    [d for d in self._up() if not d.killed], self.ticks)
+                self._observe_balancer()
+            return bool(self.arrivals or self.queue or self._steppable()
+                        or self._failed_pending()
+                        or (self.recovery and self.recovery.suspended))
 
     def run(self, max_ticks: Optional[int] = None) -> dict[str, Any]:
         limit = max_ticks if max_ticks is not None else self.rcfg.max_ticks

@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.cluster.router import ClusterRouter, RouterConfig
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.events import ServeEvent
 
@@ -174,48 +175,50 @@ class AsyncServer:
 
     # -------------------------------------------------------------- pump
     def _fanout(self) -> None:
-        for ev in self.router.drain_events():
-            rec = self.records.get(ev.request_id)
-            if rec is None:      # submitted around the server (tests)
-                continue
-            if ev.rejected:
-                rec.rejected = True
-                self._m_rejected.inc()
-            else:
-                if self._mreg.enabled:
-                    self._m_tokens.inc()
-                    if not rec.times:   # first token: TTFT vs arrival
-                        self._m_ttft.observe(
-                            max(ev.time - rec.arrival, 0.0))
-                    else:               # later tokens: pooled ITL gap
-                        self._m_itl.observe(
-                            max(ev.time - rec.times[-1], 0.0))
-                rec.tokens.append(ev.token)
-                rec.times.append(ev.time)
-                rec.indices.append(ev.index)
-            if ev.done:
-                rec.done = True
-                if not ev.rejected:
-                    self._m_finished.inc()
-                    if self._mreg.enabled and len(rec.times) > 1:
-                        gaps = np.maximum(np.diff(rec.times), 0.0)
-                        self._m_tpot.observe(float(np.mean(gaps)))
-            handle = self._handles.get(ev.request_id)
-            if handle is not None:
-                handle._push(ev)
+        with obs_trace.span("server.fanout"):
+            for ev in self.router.drain_events():
+                rec = self.records.get(ev.request_id)
+                if rec is None:      # submitted around the server (tests)
+                    continue
+                if ev.rejected:
+                    rec.rejected = True
+                    self._m_rejected.inc()
+                else:
+                    if self._mreg.enabled:
+                        self._m_tokens.inc()
+                        if not rec.times:   # first token: TTFT vs arrival
+                            self._m_ttft.observe(
+                                max(ev.time - rec.arrival, 0.0))
+                        else:               # later tokens: pooled ITL gap
+                            self._m_itl.observe(
+                                max(ev.time - rec.times[-1], 0.0))
+                    rec.tokens.append(ev.token)
+                    rec.times.append(ev.time)
+                    rec.indices.append(ev.index)
                 if ev.done:
-                    del self._handles[ev.request_id]
+                    rec.done = True
+                    if not ev.rejected:
+                        self._m_finished.inc()
+                        if self._mreg.enabled and len(rec.times) > 1:
+                            gaps = np.maximum(np.diff(rec.times), 0.0)
+                            self._m_tpot.observe(float(np.mean(gaps)))
+                handle = self._handles.get(ev.request_id)
+                if handle is not None:
+                    handle._push(ev)
+                    if ev.done:
+                        del self._handles[ev.request_id]
 
     def step(self) -> bool:
         """One pump iteration; False once the backend is drained and
         every stream has closed."""
-        if self.admission is not None:
-            self.admission.control(self.router)
-        live = self.router.tick()
-        self._fanout()
-        if self._mreg.enabled:
-            self._m_queue.set(len(self.router.queue))
-        return live or bool(self._handles)
+        with obs_trace.span("server.step"):
+            if self.admission is not None:
+                self.admission.control(self.router)
+            live = self.router.tick()
+            self._fanout()
+            if self._mreg.enabled:
+                self._m_queue.set(len(self.router.queue))
+            return live or bool(self._handles)
 
     async def drain(self, max_ticks: Optional[int] = None) -> int:
         """Pump until all submitted streams finish; returns ticks."""

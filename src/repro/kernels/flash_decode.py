@@ -383,6 +383,11 @@ def flash_decode_paged(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             pltpu.VMEM((H, d), jnp.float32),
         ],
     )
+    table_flat, bits = table.reshape(-1), _pack_mask_bits(mask, bs)
+    with jax.named_scope("kv.relayout"):
+        # the kernel reads each block as one (bs, H_kv * d) tile
+        k_flat = k_pool.reshape(NBp, bs, H_kv * d)
+        v_flat = v_pool.reshape(NBp, bs, H_kv * d)
     o, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -395,7 +400,6 @@ def flash_decode_paged(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="flash_decode_paged",
-    )(table.reshape(-1), _pack_mask_bits(mask, bs), q,
-      k_pool.reshape(NBp, bs, H_kv * d), v_pool.reshape(NBp, bs, H_kv * d))
+    )(table_flat, bits, q, k_flat, v_flat)
 
     return o, m[..., 0], l[..., 0]

@@ -261,10 +261,11 @@ def paged_masked_decode_attention(q: jax.Array, k_cache: jax.Array,
 
     # Hot partial over the ring: scores on ring coordinates, participation
     # pulled through the rotated position map.
-    ring_pos, ring_valid = ring_position_map(kv_lens, W)
-    hot_ring = ring_gather_mask(hot, ring_pos, ring_valid)
-    s_ring = _grouped_scores(q, k_cache, sc)           # (B, Hkv, rep, W)
-    part = _grouped_partial_from_scores(s_ring, v_cache, hot_ring)
+    with jax.named_scope("attn.hot"):
+        ring_pos, ring_valid = ring_position_map(kv_lens, W)
+        hot_ring = ring_gather_mask(hot, ring_pos, ring_valid)
+        s_ring = _grouped_scores(q, k_cache, sc)       # (B, Hkv, rep, W)
+        part = _grouped_partial_from_scores(s_ring, v_cache, hot_ring)
 
     # Paged partial + logical-order pool scores (the latter also feed the
     # union-mass reconstruction — the pool mirrors every token, so its
@@ -278,38 +279,44 @@ def paged_masked_decode_attention(q: jax.Array, k_cache: jax.Array,
     # pages on the kernel path.
     if use_kernel is None:
         use_kernel = _on_tpu()
-    gk = paged_gather_logical(k_pool, block_table)     # (B, Hkv, Smax, d)
-    s_pool = _grouped_scores(q, gk, sc)                # (B, Hkv, rep, Smax)
-    if use_kernel:
-        part_paged = paged_decode_attention_partial(
-            q, k_pool, v_pool, block_table, pgd, scale=sc, use_kernel=True)
-    else:
-        gv = paged_gather_logical(v_pool, block_table)
-        part_paged = _grouped_partial_from_scores(s_pool, gv, pgd)
-    merged = osm.merge_partials(part, part_paged)
-    out = osm.finalize(merged, out_dtype=q.dtype)
+    with jax.named_scope("pam.mass"):
+        gk = paged_gather_logical(k_pool, block_table)  # (B, Hkv, Smax, d)
+        s_pool = _grouped_scores(q, gk, sc)            # (B, Hkv, rep, Smax)
+    with jax.named_scope("attn.paged"):
+        if use_kernel:
+            part_paged = paged_decode_attention_partial(
+                q, k_pool, v_pool, block_table, pgd, scale=sc,
+                use_kernel=True)
+        else:
+            gv = paged_gather_logical(v_pool, block_table)
+            part_paged = _grouped_partial_from_scores(s_pool, gv, pgd)
+    with jax.named_scope("attn.merge"):
+        merged = osm.merge_partials(part, part_paged)
+        out = osm.finalize(merged, out_dtype=q.dtype)
 
     # Union mass in absolute coordinates from the merged (m, l).
-    m = merged.m.reshape(B, Hkv, rep)
-    l = merged.l.reshape(B, Hkv, rep)
-    m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
-    inv_l = 1.0 / jnp.maximum(l, 1e-30)[..., None]
+    with jax.named_scope("pam.mass"):
+        m = merged.m.reshape(B, Hkv, rep)
+        l = merged.l.reshape(B, Hkv, rep)
+        m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
+        inv_l = 1.0 / jnp.maximum(l, 1e-30)[..., None]
 
-    def probs(s, mask):
-        s = jnp.where(mask[:, None, None, :], s, -jnp.inf)
-        p = jnp.exp(s - m_safe[..., None]) * inv_l
-        return jnp.where(jnp.isfinite(s), p, 0.0)
+        def probs(s, mask):
+            s = jnp.where(mask[:, None, None, :], s, -jnp.inf)
+            p = jnp.exp(s - m_safe[..., None]) * inv_l
+            return jnp.where(jnp.isfinite(s), p, 0.0)
 
-    ph = jnp.mean(probs(s_ring, hot_ring), axis=(1, 2))      # (B, W)
-    pp = jnp.mean(probs(s_pool, pgd), axis=(1, 2))           # (B, Smax)
-    bidx = jnp.arange(B)[:, None]
-    scatter_idx = jnp.clip(ring_pos, 0, Smax - 1)
-    mass = pp.at[bidx, scatter_idx].add(jnp.where(hot_ring, ph, 0.0))
-    hot_eff = jnp.zeros((B, Smax), jnp.int32).at[bidx, scatter_idx].max(
-        hot_ring.astype(jnp.int32)).astype(bool)       # hot ∩ window, abs
-    n_live = jnp.sum(hot_eff | pgd, axis=-1,
-                     keepdims=True).astype(jnp.float32)
-    return out, mass * n_live
+        ph = jnp.mean(probs(s_ring, hot_ring), axis=(1, 2))      # (B, W)
+        pp = jnp.mean(probs(s_pool, pgd), axis=(1, 2))           # (B, Smax)
+        bidx = jnp.arange(B)[:, None]
+        scatter_idx = jnp.clip(ring_pos, 0, Smax - 1)
+        mass = pp.at[bidx, scatter_idx].add(jnp.where(hot_ring, ph, 0.0))
+        hot_eff = jnp.zeros((B, Smax), jnp.int32).at[
+            bidx, scatter_idx].max(
+            hot_ring.astype(jnp.int32)).astype(bool)   # hot ∩ window, abs
+        n_live = jnp.sum(hot_eff | pgd, axis=-1,
+                         keepdims=True).astype(jnp.float32)
+        return out, mass * n_live
 
 
 def pam_decode_attention(q: jax.Array,

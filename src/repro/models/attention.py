@@ -304,15 +304,16 @@ def attention_decode(p: AttnParams, x: jax.Array, k_cache: jax.Array,
     recovers logical order.
     """
     B, d = x.shape
-    q = jnp.einsum("bd,de->be", x, p.wq).reshape(B, n_heads, d_head)
-    k = jnp.einsum("bd,de->be", x, p.wk).reshape(B, n_kv, d_head)
-    v = jnp.einsum("bd,de->be", x, p.wv).reshape(B, n_kv, d_head)
-    if p.q_norm is not None:
-        q = rms_norm(q, p.q_norm, rms_eps)
-        k = rms_norm(k, p.k_norm, rms_eps)
-    pos = kv_lens                                       # (B,)
-    q = apply_rope(q[:, None], pos[:, None], rope_theta)[:, 0]
-    k = apply_rope(k[:, None], pos[:, None], rope_theta)[:, 0]
+    with jax.named_scope("model.qkv"):
+        q = jnp.einsum("bd,de->be", x, p.wq).reshape(B, n_heads, d_head)
+        k = jnp.einsum("bd,de->be", x, p.wk).reshape(B, n_kv, d_head)
+        v = jnp.einsum("bd,de->be", x, p.wv).reshape(B, n_kv, d_head)
+        if p.q_norm is not None:
+            q = rms_norm(q, p.q_norm, rms_eps)
+            k = rms_norm(k, p.k_norm, rms_eps)
+        pos = kv_lens                                   # (B,)
+        q = apply_rope(q[:, None], pos[:, None], rope_theta)[:, 0]
+        k = apply_rope(k[:, None], pos[:, None], rope_theta)[:, 0]
 
     from repro.models import perf_flags
     if perf_flags.enabled("pam_shard_decode"):
@@ -331,19 +332,24 @@ def attention_decode(p: AttnParams, x: jax.Array, k_cache: jax.Array,
         # this one write is also the ring eviction (the overwritten
         # token's bytes live on in its mapped pool block); a full-window
         # buffer reduces to the absolute position
-        bidx = jnp.arange(B)
-        slot = pos % k_cache.shape[2]
-        k_cache = k_cache.at[bidx, :, slot].set(k)
-        v_cache = v_cache.at[bidx, :, slot].set(v)
+        with jax.named_scope("kv.append"):
+            bidx = jnp.arange(B)
+            slot = pos % k_cache.shape[2]
+            k_cache = k_cache.at[bidx, :, slot].set(k)
+            v_cache = v_cache.at[bidx, :, slot].set(v)
+            if paged is not None:
+                pk, pv, dst_block, dst_slot = paged
+                pk = pk.at[dst_block, dst_slot].set(k)
+                pv = pv.at[dst_block, dst_slot].set(v)
         if paged is not None:
-            pk, pv, dst_block, dst_slot = paged
-            pk = pk.at[dst_block, dst_slot].set(k)
-            pv = pv.at[dst_block, dst_slot].set(v)
             out, mass = decode_attn_fn(q, k_cache, v_cache, pk, pv,
                                        kv_lens + 1)
-            out = out.reshape(B, n_heads * d_head)
-            return (jnp.einsum("be,ed->bd", out, p.wo), mass,
-                    k_cache, v_cache, pk, pv)
+            with jax.named_scope("model.attn_out"):
+                out = out.reshape(B, n_heads * d_head)
+                out = jnp.einsum("be,ed->bd", out, p.wo)
+            return out, mass, k_cache, v_cache, pk, pv
         out, mass = decode_attn_fn(q, k_cache, v_cache, kv_lens + 1)
-    out = out.reshape(B, n_heads * d_head)
-    return jnp.einsum("be,ed->bd", out, p.wo), mass, k_cache, v_cache
+    with jax.named_scope("model.attn_out"):
+        out = out.reshape(B, n_heads * d_head)
+        return (jnp.einsum("be,ed->bd", out, p.wo), mass, k_cache,
+                v_cache)

@@ -289,7 +289,8 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
     subsequent decode appends. Only valid for positional-cache families
     (attention); SSM/hybrid running state would absorb the padding."""
     B, S = tokens.shape
-    x = params["embed"][tokens]
+    with jax.named_scope("model.embed"):
+        x = params["embed"][tokens]
     n_prefix = 0
     if cfg.family == "vlm" and patches is not None:
         px = jnp.einsum("bpf,fd->bpd", patches, params["frontend"])
@@ -317,22 +318,28 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
                                           and cfg.mla is None):
         def body(carry, layer):
             h = carry
-            hn = rms_norm(h, layer["ln1"], cfg.rms_eps)
-            attn_out, k, v = attn_mod.attention_prefill(
-                _attn_params(layer), hn, n_heads=cfg.n_heads,
-                n_kv=cfg.n_kv_heads, d_head=cfg.head_dim, causal=cfg.causal,
-                rope_theta=cfg.rope_theta, rms_eps=cfg.rms_eps)
-            h = h + attn_out
-            hn = rms_norm(h, layer["ln2"], cfg.rms_eps)
-            if cfg.moe is not None:
-                ffn, _ = moe_mod.moe_forward(_moe_params(layer), hn, cfg.moe)
-            else:
-                m = layer["mlp"]
-                ffn = swiglu(hn, m["gate"], m["up"], m["down"])
-            return h + ffn, (k, v)
+            with jax.named_scope("model.prefill_attention"):
+                hn = rms_norm(h, layer["ln1"], cfg.rms_eps)
+                attn_out, k, v = attn_mod.attention_prefill(
+                    _attn_params(layer), hn, n_heads=cfg.n_heads,
+                    n_kv=cfg.n_kv_heads, d_head=cfg.head_dim,
+                    causal=cfg.causal, rope_theta=cfg.rope_theta,
+                    rms_eps=cfg.rms_eps)
+                h = h + attn_out
+            with jax.named_scope("model.prefill_mlp"):
+                hn = rms_norm(h, layer["ln2"], cfg.rms_eps)
+                if cfg.moe is not None:
+                    ffn, _ = moe_mod.moe_forward(_moe_params(layer), hn,
+                                                 cfg.moe)
+                else:
+                    m = layer["mlp"]
+                    ffn = swiglu(hn, m["gate"], m["up"], m["down"])
+                return h + ffn, (k, v)
 
-        x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
-        cache = cache._replace(k=pad_seq(ks, 3), v=pad_seq(vs, 3))
+        with jax.named_scope("model.layers"):
+            x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
+        with jax.named_scope("kv.prefill_cache"):
+            cache = cache._replace(k=pad_seq(ks, 3), v=pad_seq(vs, 3))
 
     elif cfg.family == "moe":                      # MLA
         def body(carry, layer):
@@ -400,14 +407,16 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
         raise ValueError(f"{cfg.name}: prefill unsupported for family "
                          f"{cfg.family}")
 
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    if true_len is None:
-        last = x[:, -1]
-    else:   # last REAL token of each (possibly bucket-padded) prompt
-        last = jnp.take_along_axis(x, (lens - 1)[:, None, None],
-                                   axis=1)[:, 0]
-    logits = jnp.einsum("bd,dv->bv", last, head)
+    with jax.named_scope("model.head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        if true_len is None:
+            last = x[:, -1]
+        else:   # last REAL token of each (possibly bucket-padded) prompt
+            last = jnp.take_along_axis(x, (lens - 1)[:, None, None],
+                                       axis=1)[:, 0]
+        logits = jnp.einsum("bd,dv->bv", last, head)
     return logits, cache._replace(lengths=lens)
 
 
@@ -440,7 +449,8 @@ def prefill_suffix(cfg: ModelConfig, params: Params, tokens: jax.Array,
             f"suffix prefill needs a token-only GQA cache; family "
             f"{cfg.family} is not supported")
     B, S = tokens.shape
-    x = params["embed"][tokens]
+    with jax.named_scope("model.embed"):
+        x = params["embed"][tokens]
     plen = jnp.broadcast_to(jnp.asarray(prefix_len, jnp.int32), (B,))
     if true_len is None:
         slen = jnp.full((B,), S, jnp.int32)
@@ -450,27 +460,34 @@ def prefill_suffix(cfg: ModelConfig, params: Params, tokens: jax.Array,
     def body(carry, inp):
         h = carry
         layer, pk_l, pv_l = inp
-        hn = rms_norm(h, layer["ln1"], cfg.rms_eps)
-        attn_out, k, v = attn_mod.attention_prefill_with_prefix(
-            _attn_params(layer), hn, pk_l, pv_l, plen,
-            n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
-            d_head=cfg.head_dim, rope_theta=cfg.rope_theta,
-            rms_eps=cfg.rms_eps)
-        h = h + attn_out
-        hn = rms_norm(h, layer["ln2"], cfg.rms_eps)
-        if cfg.moe is not None:
-            ffn, _ = moe_mod.moe_forward(_moe_params(layer), hn, cfg.moe)
-        else:
-            m = layer["mlp"]
-            ffn = swiglu(hn, m["gate"], m["up"], m["down"])
-        return h + ffn, (k, v)
+        with jax.named_scope("model.prefill_attention"):
+            hn = rms_norm(h, layer["ln1"], cfg.rms_eps)
+            attn_out, k, v = attn_mod.attention_prefill_with_prefix(
+                _attn_params(layer), hn, pk_l, pv_l, plen,
+                n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
+                d_head=cfg.head_dim, rope_theta=cfg.rope_theta,
+                rms_eps=cfg.rms_eps)
+            h = h + attn_out
+        with jax.named_scope("model.prefill_mlp"):
+            hn = rms_norm(h, layer["ln2"], cfg.rms_eps)
+            if cfg.moe is not None:
+                ffn, _ = moe_mod.moe_forward(_moe_params(layer), hn,
+                                             cfg.moe)
+            else:
+                m = layer["mlp"]
+                ffn = swiglu(hn, m["gate"], m["up"], m["down"])
+            return h + ffn, (k, v)
 
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"],
-                                         prefix_k, prefix_v))
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    last = jnp.take_along_axis(x, (slen - 1)[:, None, None], axis=1)[:, 0]
-    return jnp.einsum("bd,dv->bv", last, head), ks, vs
+    with jax.named_scope("model.layers"):
+        x, (ks, vs) = jax.lax.scan(body, x, (params["layers"],
+                                             prefix_k, prefix_v))
+    with jax.named_scope("model.head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        last = jnp.take_along_axis(x, (slen - 1)[:, None, None],
+                                   axis=1)[:, 0]
+        return jnp.einsum("bd,dv->bv", last, head), ks, vs
 
 
 # ============================================================ decode
@@ -592,7 +609,8 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
         raise ValueError(f"{cfg.name} is encoder-only")
     d_fn = decode_attn_fn or attn_mod.dense_decode_attn
     l_fn = latent_attn_fn or mla_mod.mla_latent_decode_attn
-    x = params["embed"][tokens]                       # (B, d)
+    with jax.named_scope("model.embed"):
+        x = params["embed"][tokens]                   # (B, d)
     lens = cache.lengths
     scores: Optional[jax.Array] = None
     use_paged = cache.pk.size > 0
@@ -610,7 +628,8 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
             else:
                 layer, kc, vc = inp
                 paged = None
-            hn = rms_norm(h, layer["ln1"], cfg.rms_eps)
+            with jax.named_scope("model.qkv"):
+                hn = rms_norm(h, layer["ln1"], cfg.rms_eps)
             res = attn_mod.attention_decode(
                 _attn_params(layer), hn, kc, vc, lens,
                 n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
@@ -620,28 +639,36 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
                 attn_out, mass, kc, vc, pk, pv = res
             else:
                 attn_out, mass, kc, vc = res
-            h = h + attn_out
-            hn = rms_norm(h, layer["ln2"], cfg.rms_eps)
-            if cfg.moe is not None:
-                ffn, _ = moe_mod.moe_forward(_moe_params(layer),
-                                             hn[:, None], cfg.moe)
-                ffn = ffn[:, 0]
-            else:
-                m = layer["mlp"]
-                ffn = swiglu(hn, m["gate"], m["up"], m["down"])
+            with jax.named_scope("model.mlp"):
+                h = h + attn_out
+                hn = rms_norm(h, layer["ln2"], cfg.rms_eps)
+                if cfg.moe is not None:
+                    ffn, _ = moe_mod.moe_forward(_moe_params(layer),
+                                                 hn[:, None], cfg.moe)
+                    ffn = ffn[:, 0]
+                else:
+                    m = layer["mlp"]
+                    ffn = swiglu(hn, m["gate"], m["up"], m["down"])
+                h = h + ffn
             ys = (kc, vc, pk, pv, mass) if use_paged else (kc, vc, mass)
-            return h + ffn, ys
+            return h, ys
 
-        if use_paged:
-            x, (k_new, v_new, pk_new, pv_new, masses) = jax.lax.scan(
-                body, x, (params["layers"], cache.k, cache.v,
-                          cache.pk, cache.pv))
-            cache = cache._replace(k=k_new, v=v_new, pk=pk_new, pv=pv_new)
-        else:
-            x, (k_new, v_new, masses) = jax.lax.scan(
-                body, x, (params["layers"], cache.k, cache.v))
-            cache = cache._replace(k=k_new, v=v_new)
-        scores = jnp.mean(masses, axis=0)
+        # the scope names the layer loop's own work: slicing each layer's
+        # weights, caches and pools out of the stacks, and stacking them
+        # back as the loop's outputs
+        with jax.named_scope("model.layers"):
+            if use_paged:
+                x, (k_new, v_new, pk_new, pv_new, masses) = jax.lax.scan(
+                    body, x, (params["layers"], cache.k, cache.v,
+                              cache.pk, cache.pv))
+                cache = cache._replace(k=k_new, v=v_new, pk=pk_new,
+                                       pv=pv_new)
+            else:
+                x, (k_new, v_new, masses) = jax.lax.scan(
+                    body, x, (params["layers"], cache.k, cache.v))
+                cache = cache._replace(k=k_new, v=v_new)
+        with jax.named_scope("pam.mass"):
+            scores = jnp.mean(masses, axis=0)
 
     elif cfg.family == "moe":                          # MLA path
         def body(carry, inp):
@@ -731,7 +758,9 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
     else:
         raise ValueError(cfg.family)
 
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bd,dv->bv", x, head)
+    with jax.named_scope("model.head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = jnp.einsum("bd,dv->bv", x, head)
     return logits, cache._replace(lengths=lens + 1), scores

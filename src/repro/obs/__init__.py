@@ -12,7 +12,10 @@ disabled, shared by the engine (``repro.serving``), the cluster
   chunked-prefill slices → decode → suspend/migrate → finish/shed) and
   engine-step / cluster-tick events on the existing sim-clocks,
   recorded into a bounded ring and exported as Chrome trace-event JSON
-  loadable in Perfetto.
+  loadable in Perfetto; ``repro.obs.trace.span`` puts host spans on
+  the JAX profiler's own clock instead, beside the device ops, whose
+  ``jax.named_scope`` paths (``pam.*``, ``kv.*``, ``attn.*``,
+  ``model.*``) say which part of the program each op belongs to.
 
 Enable both for a run with::
 

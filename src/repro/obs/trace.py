@@ -38,6 +38,8 @@ import contextlib
 import json
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 REQUEST_CAT = "request"
 _REQUEST_PID = 1
 _DEVICE_PID0 = 10
@@ -215,6 +217,18 @@ def use(coll: Optional[TraceCollector] = None):
         yield COLLECTOR
     finally:
         COLLECTOR = prev
+
+
+# ------------------------------------------------ profiler-clock spans
+def span(name: str) -> TraceAnnotation:
+    """Host span ``name`` on the JAX profiler's timeline, beside the
+    device ops of the same run. It records only while a profiler session
+    is open (``jax.profiler.trace``); otherwise entering it is one
+    enabled check. Unlike the collector above it needs no install: wrap
+    a run in ``jax.profiler.trace(dir)`` and load the result in Perfetto
+    or XProf. Names are dotted by layer (``server.step``,
+    ``engine.decode_dispatch``; docs/ARCHITECTURE.md lists them)."""
+    return TraceAnnotation(name)
 
 
 # ------------------------------------------------------ schema validation

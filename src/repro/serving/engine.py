@@ -158,6 +158,7 @@ class RequestState:
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     token_times: list[float] = dataclasses.field(default_factory=list)
+    submit_clock: float = 0.0          # engine clock at submit
 
 
 @dataclasses.dataclass(frozen=True)
@@ -246,21 +247,22 @@ def _sample_tokens(logits, seed: int, rids, positions,
     replay-stability is what makes sampled streams bit-identical across
     migration AND failure recovery (a replayed request regenerates the
     exact tokens it already emitted — ``repro.cluster.recovery``)."""
-    if temperature == 0.0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    lg = logits.astype(jnp.float32) / temperature
-    if 0 < top_k < lg.shape[-1]:
-        kth = jax.lax.top_k(lg, top_k)[0][..., -1:]
-        lg = jnp.where(lg < kth, -jnp.inf, lg)
-    base = jax.random.PRNGKey(seed)
+    with jax.named_scope("model.sample"):
+        if temperature == 0.0:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        lg = logits.astype(jnp.float32) / temperature
+        if 0 < top_k < lg.shape[-1]:
+            kth = jax.lax.top_k(lg, top_k)[0][..., -1:]
+            lg = jnp.where(lg < kth, -jnp.inf, lg)
+        base = jax.random.PRNGKey(seed)
 
-    def draw(rid, pos, row):
-        key = jax.random.fold_in(jax.random.fold_in(base, rid), pos)
-        return jax.random.categorical(key, row, axis=-1)
+        def draw(rid, pos, row):
+            key = jax.random.fold_in(jax.random.fold_in(base, rid), pos)
+            return jax.random.categorical(key, row, axis=-1)
 
-    return jax.vmap(draw)(rids.astype(jnp.uint32),
-                          positions.astype(jnp.uint32),
-                          lg).astype(jnp.int32)
+        return jax.vmap(draw)(rids.astype(jnp.uint32),
+                              positions.astype(jnp.uint32),
+                              lg).astype(jnp.int32)
 
 
 def _fused_decode_body(cfg: ModelConfig, pcfg: Optional[PAMManagerConfig],
@@ -291,26 +293,30 @@ def _fused_decode_body(cfg: ModelConfig, pcfg: Optional[PAMManagerConfig],
     lengths and token for the remaining micro-steps.
     """
     B = active.shape[0]
-    lengths = cache.lengths + active.astype(jnp.int32)
-    if pcfg is not None:
-        participate = pm.participation_mask(
-            pcfg, pam_state.importance, lengths)
-    else:
-        participate = jnp.arange(smax)[None, :] < lengths[:, None]
+    with jax.named_scope("pam.participation"):
+        lengths = cache.lengths + active.astype(jnp.int32)
+        if pcfg is not None:
+            participate = pm.participation_mask(
+                pcfg, pam_state.importance, lengths)
+        else:
+            participate = jnp.arange(smax)[None, :] < lengths[:, None]
     l_fn = make_masked_latent_attn(participate)
     paged_append = None
     blocks = jnp.zeros((2,), jnp.int32)
     if bs:
         nb = smax // bs
-        if hot_window:
-            # ring demotion, part 2: the append overwrote the evicted
-            # slot; re-tag tokens that slid out of the window so the
-            # split (and the tier accounting) reads them from the pool
-            pam_state = pam_state._replace(tier=tiers_mod.clamp_hot_to_window(
-                pam_state.tier, lengths, hot_window))
-        hot_m, pgd_m, block_live = pm.paged_participation_split(
-            participate, pam_state.tier, lengths, bs, hot_window)
-        bt_eff = jnp.where(block_live, pam_state.block_table, sentinel)
+        with jax.named_scope("pam.participation"):
+            if hot_window:
+                # ring demotion, part 2: the append overwrote the evicted
+                # slot; re-tag tokens that slid out of the window so the
+                # split (and the tier accounting) reads them from the pool
+                pam_state = pam_state._replace(
+                    tier=tiers_mod.clamp_hot_to_window(
+                        pam_state.tier, lengths, hot_window))
+            hot_m, pgd_m, block_live = pm.paged_participation_split(
+                participate, pam_state.tier, lengths, bs, hot_window)
+            bt_eff = jnp.where(block_live, pam_state.block_table,
+                               sentinel)
         if mesh is not None:
             # PR 10: hot ring + pool reads fan out over the mesh's
             # "model" axis under shard_map; partials re-merge with the
@@ -323,50 +329,56 @@ def _fused_decode_body(cfg: ModelConfig, pcfg: Optional[PAMManagerConfig],
             d_fn = pm.make_paged_decode_attn(hot_m, pgd_m, bt_eff)
         # append coordinates for the new token (same for every layer);
         # inactive rows write the sentinel trash page
-        pos = cache.lengths
-        lb = jnp.clip(pos // bs, 0, nb - 1)
-        dst_block = jnp.where(
-            active, pam_state.block_table[jnp.arange(B), lb], sentinel)
-        paged_append = (dst_block.astype(jnp.int32),
-                        (pos % bs).astype(jnp.int32))
-        valid = jnp.arange(smax)[None, :] < lengths[:, None]
-        window = pkv.token_block_mask(valid, bs)
-        act = active[:, None]
-        blocks = jnp.stack([jnp.sum(block_live & act),
-                            jnp.sum(window & act)]).astype(jnp.int32)
+        with jax.named_scope("kv.append"):
+            pos = cache.lengths
+            lb = jnp.clip(pos // bs, 0, nb - 1)
+            dst_block = jnp.where(
+                active, pam_state.block_table[jnp.arange(B), lb], sentinel)
+            paged_append = (dst_block.astype(jnp.int32),
+                            (pos % bs).astype(jnp.int32))
+        with jax.named_scope("pam.stats"):
+            valid = jnp.arange(smax)[None, :] < lengths[:, None]
+            window = pkv.token_block_mask(valid, bs)
+            act = active[:, None]
+            blocks = jnp.stack([jnp.sum(block_live & act),
+                                jnp.sum(window & act)]).astype(jnp.int32)
     else:
         d_fn = make_masked_decode_attn(participate)
     old_lens = cache.lengths
     logits, cache, scores = tf.decode_step(
         cfg, params, tokens, cache, decode_attn_fn=d_fn,
         latent_attn_fn=l_fn, paged_append=paged_append)
-    # inactive slots: freeze their lengths
-    cache = cache._replace(
-        lengths=jnp.where(active, cache.lengths, old_lens))
+    with jax.named_scope("kv.append"):
+        # inactive slots: freeze their lengths
+        cache = cache._replace(
+            lengths=jnp.where(active, cache.lengths, old_lens))
 
     if pcfg is not None:
-        read_mask = participate & active[:, None]
-        tier_reads = pm.tier_read_counts_of(pam_state.tier, read_mask)
-        hit = pm.hit_rate_of(pam_state.last_hot, participate)
+        with jax.named_scope("pam.stats"):
+            read_mask = participate & active[:, None]
+            tier_reads = pm.tier_read_counts_of(pam_state.tier, read_mask)
+            hit = pm.hit_rate_of(pam_state.last_hot, participate)
         if scores is None:     # attention-free: recency-only scores
             scores = (jnp.arange(smax)[None, :]
                       == (cache.lengths - 1)[:, None]).astype(jnp.float32)
         before = pam_state.moved_tokens
         pam_state = pm.observe_update(pcfg, pam_state, scores,
                                       cache.lengths, participate)
-        moved = pam_state.moved_tokens - before
+        with jax.named_scope("pam.stats"):
+            moved = pam_state.moved_tokens - before
     else:
         tier_reads = jnp.zeros((3,), jnp.int32)
         hit = jnp.zeros((), jnp.float32)
         moved = jnp.zeros((), jnp.int32)
 
-    # the sampled token's absolute position is the post-append cache
-    # length — the (rid, position) pair keys the per-request PRNG
-    nxt = _sample_tokens(logits, seed, rids, cache.lengths,
-                         temperature, top_k)
-    tokens = jnp.where(active, nxt, tokens)
-    if eos >= 0:
-        active = active & (tokens != eos)   # EOS emitted -> slot freezes
+    with jax.named_scope("model.head"):
+        # the sampled token's absolute position is the post-append cache
+        # length — the (rid, position) pair keys the per-request PRNG
+        nxt = _sample_tokens(logits, seed, rids, cache.lengths,
+                             temperature, top_k)
+        tokens = jnp.where(active, nxt, tokens)
+        if eos >= 0:
+            active = active & (tokens != eos)   # EOS emitted -> freeze
     return tokens, cache, pam_state, active, (tier_reads, hit, moved,
                                               cache.lengths, blocks)
 
@@ -402,13 +414,14 @@ def _fused_decode_fn(cfg: ModelConfig, pcfg: Optional[PAMManagerConfig],
                     cfg, pcfg, smax, bs, sentinel, temperature, top_k,
                     eos, hot_window, seed, mesh, params, tokens, cache,
                     pam_state, active, rids)
-            bufs = StepBufs(
-                tokens=bufs.tokens.at[i].set(tokens),
-                tier_reads=bufs.tier_reads.at[i].set(reads),
-                hit_rate=bufs.hit_rate.at[i].set(hit),
-                moved=bufs.moved.at[i].set(moved),
-                lengths=bufs.lengths.at[i].set(lens),
-                blocks=bufs.blocks.at[i].set(blk))
+            with jax.named_scope("pam.stats"):
+                bufs = StepBufs(
+                    tokens=bufs.tokens.at[i].set(tokens),
+                    tier_reads=bufs.tier_reads.at[i].set(reads),
+                    hit_rate=bufs.hit_rate.at[i].set(hit),
+                    moved=bufs.moved.at[i].set(moved),
+                    lengths=bufs.lengths.at[i].set(lens),
+                    blocks=bufs.blocks.at[i].set(blk))
             return tokens, cache, pam_state, active, bufs
 
         carry = (tokens, cache, pam_state, active, bufs)
@@ -472,8 +485,8 @@ def _admit_commit_fn(pcfg: Optional[PAMManagerConfig], block_size: int,
     their pool blocks from the moment of admission.
     ``n == 1`` is the single-admission case; same-bucket admission
     bursts ride one dispatch."""
-    def commit(cache, pam_state, tokens_dev, sub, logits, slots, lengths,
-               rids, table_rows=None):
+    def admit_commit(cache, pam_state, tokens_dev, sub, logits, slots,
+                     lengths, rids, table_rows=None):
         # first token = absolute position `prompt_len` of request `rid`
         firsts = _sample_tokens(logits, seed, rids, lengths,
                                 temperature, top_k)
@@ -494,17 +507,22 @@ def _admit_commit_fn(pcfg: Optional[PAMManagerConfig], block_size: int,
                 pv = pkv.write_prefill(pv, sub.v[:, i], table_rows[i],
                                        block_size)
             if hot_window:
-                ring_pos, valid = ring_position_map(lengths, hot_window)
-                ring_of = jax.vmap(pam_if.logical_to_ring,
-                                   in_axes=(1, 0, 0), out_axes=1)
-                sub = sub._replace(k=ring_of(sub.k, ring_pos, valid),
-                                   v=ring_of(sub.v, ring_pos, valid))
-            cache = cache._replace(pk=sub.pk, pv=sub.pv)
-            cache = jax.tree.map(put, cache, sub)
-            cache = cache._replace(pk=pk, pv=pv)
+                with jax.named_scope("kv.ring_relayout"):
+                    ring_pos, valid = ring_position_map(lengths,
+                                                        hot_window)
+                    ring_of = jax.vmap(pam_if.logical_to_ring,
+                                       in_axes=(1, 0, 0), out_axes=1)
+                    sub = sub._replace(k=ring_of(sub.k, ring_pos, valid),
+                                       v=ring_of(sub.v, ring_pos, valid))
+            with jax.named_scope("kv.slot_install"):
+                cache = cache._replace(pk=sub.pk, pv=sub.pv)
+                cache = jax.tree.map(put, cache, sub)
+                cache = cache._replace(pk=pk, pv=pv)
         else:
-            cache = jax.tree.map(put, cache, sub)
-        tokens_dev = tokens_dev.at[slots].set(firsts)
+            with jax.named_scope("kv.slot_install"):
+                cache = jax.tree.map(put, cache, sub)
+        with jax.named_scope("model.sample"):
+            tokens_dev = tokens_dev.at[slots].set(firsts)
         if pcfg is not None:
             for i in range(n):
                 pam_state = pm.place_prefill_state(
@@ -514,9 +532,9 @@ def _admit_commit_fn(pcfg: Optional[PAMManagerConfig], block_size: int,
 
     if cache_shardings is not None:
         rep = cache_shardings.lengths
-        return jax.jit(commit, donate_argnums=(0, 1, 2),
+        return jax.jit(admit_commit, donate_argnums=(0, 1, 2),
                        out_shardings=(cache_shardings, rep, rep, rep))
-    return jax.jit(commit, donate_argnums=(0, 1, 2))
+    return jax.jit(admit_commit, donate_argnums=(0, 1, 2))
 
 
 @functools.lru_cache(maxsize=None)
@@ -531,17 +549,19 @@ def _suffix_prefill_fn(cfg: ModelConfig, smax: int, rep=None):
     the from-scratch prefill. One dispatch; retraces per (group size,
     suffix bucket) like ``_prefill_fn``. Returns (last-token logits
     (n, V), suffix K/V (L, n, Hkv, S, dh))."""
-    def pre(params, tokens, pk, pv, read_rows, prefix_lens, true_lens):
-        gather = jax.vmap(pam_if.gather_prefix_logical,
-                          in_axes=(None, 0, 0), out_axes=1)
-        gk = gather(pk, read_rows, prefix_lens)    # (L, n, Hkv, P, dh)
-        gv = gather(pv, read_rows, prefix_lens)
+    def suffix_pre(params, tokens, pk, pv, read_rows, prefix_lens,
+                   true_lens):
+        with jax.named_scope("kv.prefix_gather"):
+            gather = jax.vmap(pam_if.gather_prefix_logical,
+                              in_axes=(None, 0, 0), out_axes=1)
+            gk = gather(pk, read_rows, prefix_lens)  # (L, n, Hkv, P, dh)
+            gv = gather(pv, read_rows, prefix_lens)
         return tf.prefill_suffix(cfg, params, tokens, gk, gv,
                                  prefix_lens, true_len=true_lens)
 
     if rep is not None:
-        return jax.jit(pre, out_shardings=(rep, rep, rep))
-    return jax.jit(pre)
+        return jax.jit(suffix_pre, out_shardings=(rep, rep, rep))
+    return jax.jit(suffix_pre)
 
 
 @functools.lru_cache(maxsize=None)
@@ -571,41 +591,45 @@ def _suffix_commit_fn(pcfg: Optional[PAMManagerConfig], block_size: int,
     The donation/one-dispatch invariants match ``_admit_commit_fn``: a
     burst of n same-bucket admissions costs 2 dispatches whether or not
     any of them hit the prefix cache."""
-    def commit(cache, pam_state, tokens_dev, suf_k, suf_v, logits,
-               slots, lengths, rids, table_rows, bids, sids, cow_srcs,
-               cow_dsts):
+    def suffix_commit(cache, pam_state, tokens_dev, suf_k, suf_v, logits,
+                      slots, lengths, rids, table_rows, bids, sids,
+                      cow_srcs, cow_dsts):
         pk, pv = cache.pk, cache.pv
         for i in range(n):
             pk = pkv.copy_block(pk, cow_srcs[i], cow_dsts[i])
             pv = pkv.copy_block(pv, cow_srcs[i], cow_dsts[i])
-        sk = jnp.moveaxis(suf_k, 2, 3)             # (L, n, S, Hkv, dh)
-        sv = jnp.moveaxis(suf_v, 2, 3)
-        pk = pk.at[:, bids, sids].set(sk)          # bids/sids: (n, S)
-        pv = pv.at[:, bids, sids].set(sv)
-        gat = jax.vmap(pkv.gather_sequence, in_axes=(None, 0),
-                       out_axes=1)
-        gk = gat(pk, table_rows)                   # (L, n, Hkv, smax, dh)
-        gv = gat(pv, table_rows)
-        live = (jnp.arange(gk.shape[3])[None, None, None, :, None]
-                < lengths[None, :, None, None, None])
-        gk = jnp.where(live, gk, jnp.zeros((), gk.dtype))
-        gv = jnp.where(live, gv, jnp.zeros((), gv.dtype))
-        if hot_window:
-            ring_pos, valid = ring_position_map(lengths, hot_window)
-            ring_of = jax.vmap(pam_if.logical_to_ring,
-                               in_axes=(1, 0, 0), out_axes=1)
-            dk = ring_of(gk, ring_pos, valid)
-            dv = ring_of(gv, ring_pos, valid)
-        else:
-            dk, dv = gk, gv
-        cache = cache._replace(
-            k=cache.k.at[:, slots].set(dk),
-            v=cache.v.at[:, slots].set(dv),
-            lengths=cache.lengths.at[slots].set(lengths),
-            pk=pk, pv=pv)
+        with jax.named_scope("kv.prefill_write"):
+            sk = jnp.moveaxis(suf_k, 2, 3)         # (L, n, S, Hkv, dh)
+            sv = jnp.moveaxis(suf_v, 2, 3)
+            pk = pk.at[:, bids, sids].set(sk)      # bids/sids: (n, S)
+            pv = pv.at[:, bids, sids].set(sv)
+        with jax.named_scope("kv.ring_relayout"):
+            gat = jax.vmap(pkv.gather_sequence, in_axes=(None, 0),
+                           out_axes=1)
+            gk = gat(pk, table_rows)               # (L, n, Hkv, smax, dh)
+            gv = gat(pv, table_rows)
+            live = (jnp.arange(gk.shape[3])[None, None, None, :, None]
+                    < lengths[None, :, None, None, None])
+            gk = jnp.where(live, gk, jnp.zeros((), gk.dtype))
+            gv = jnp.where(live, gv, jnp.zeros((), gv.dtype))
+            if hot_window:
+                ring_pos, valid = ring_position_map(lengths, hot_window)
+                ring_of = jax.vmap(pam_if.logical_to_ring,
+                                   in_axes=(1, 0, 0), out_axes=1)
+                dk = ring_of(gk, ring_pos, valid)
+                dv = ring_of(gv, ring_pos, valid)
+            else:
+                dk, dv = gk, gv
+        with jax.named_scope("kv.slot_install"):
+            cache = cache._replace(
+                k=cache.k.at[:, slots].set(dk),
+                v=cache.v.at[:, slots].set(dv),
+                lengths=cache.lengths.at[slots].set(lengths),
+                pk=pk, pv=pv)
         firsts = _sample_tokens(logits, seed, rids, lengths,
                                 temperature, top_k)
-        tokens_dev = tokens_dev.at[slots].set(firsts)
+        with jax.named_scope("model.sample"):
+            tokens_dev = tokens_dev.at[slots].set(firsts)
         if pcfg is not None:
             for i in range(n):
                 pam_state = pm.place_prefill_state(
@@ -615,9 +639,9 @@ def _suffix_commit_fn(pcfg: Optional[PAMManagerConfig], block_size: int,
 
     if cache_shardings is not None:
         rep = cache_shardings.lengths
-        return jax.jit(commit, donate_argnums=(0, 1, 2),
+        return jax.jit(suffix_commit, donate_argnums=(0, 1, 2),
                        out_shardings=(cache_shardings, rep, rep, rep))
-    return jax.jit(commit, donate_argnums=(0, 1, 2))
+    return jax.jit(suffix_commit, donate_argnums=(0, 1, 2))
 
 
 @functools.lru_cache(maxsize=None)
@@ -633,8 +657,8 @@ def _chunk_fill_fn(cfg: ModelConfig, smax: int, cow: bool = False,
     FINAL slice's suffix commit, after which the request is
     indistinguishable from a single-shot admission. The slice logits
     are discarded (only the final slice's feed sampling)."""
-    def fill(params, cache, tokens, table_row, begin, true_len, bids,
-             sids, cow_src, cow_dst):
+    def chunk_fill(params, cache, tokens, table_row, begin, true_len,
+                   bids, sids, cow_src, cow_dst):
         pk, pv = cache.pk, cache.pv
         if cow:
             # after the copy the request's own table maps cow_dst, which
@@ -642,21 +666,23 @@ def _chunk_fill_fn(cfg: ModelConfig, smax: int, cow: bool = False,
             # the prefix entirely through the request's own row
             pk = pkv.copy_block(pk, cow_src, cow_dst)
             pv = pkv.copy_block(pv, cow_src, cow_dst)
-        gk = pam_if.gather_prefix_logical(pk, table_row, begin)
-        gv = pam_if.gather_prefix_logical(pv, table_row, begin)
+        with jax.named_scope("kv.prefix_gather"):
+            gk = pam_if.gather_prefix_logical(pk, table_row, begin)
+            gv = pam_if.gather_prefix_logical(pv, table_row, begin)
         _, suf_k, suf_v = tf.prefill_suffix(
             cfg, params, tokens, gk[:, None], gv[:, None], begin[None],
             true_len=true_len)
-        sk = jnp.moveaxis(suf_k[:, 0], 1, 2)       # (L, S, Hkv, dh)
-        sv = jnp.moveaxis(suf_v[:, 0], 1, 2)
-        pk = pk.at[:, bids, sids].set(sk)
-        pv = pv.at[:, bids, sids].set(sv)
+        with jax.named_scope("kv.prefill_write"):
+            sk = jnp.moveaxis(suf_k[:, 0], 1, 2)   # (L, S, Hkv, dh)
+            sv = jnp.moveaxis(suf_v[:, 0], 1, 2)
+            pk = pk.at[:, bids, sids].set(sk)
+            pv = pv.at[:, bids, sids].set(sv)
         return cache._replace(pk=pk, pv=pv)
 
     if cache_shardings is not None:
-        return jax.jit(fill, donate_argnums=(1,),
+        return jax.jit(chunk_fill, donate_argnums=(1,),
                        out_shardings=cache_shardings)
-    return jax.jit(fill, donate_argnums=(1,))
+    return jax.jit(chunk_fill, donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -671,18 +697,22 @@ def _import_commit_fn(has_pam: bool, block_size: int,
     tokens through the rotated position map; the pool scatter below
     keeps the full context). The admission twin of ``export``: a
     migrated request resumes with zero host state left on the source."""
-    def commit(cache, pam_state, tokens_dev, k_row, v_row, imp_row,
-               tier_row, lh_row, slot, length, token, table_row=None):
-        if hot_window:
-            ring_pos, valid = ring_position_map(length[None], hot_window)
-            dk = pam_if.logical_to_ring(k_row, ring_pos[0], valid[0])
-            dv = pam_if.logical_to_ring(v_row, ring_pos[0], valid[0])
-        else:
-            dk, dv = k_row, v_row
-        cache = cache._replace(
-            k=cache.k.at[:, slot].set(dk),
-            v=cache.v.at[:, slot].set(dv),
-            lengths=cache.lengths.at[slot].set(length))
+    def import_commit(cache, pam_state, tokens_dev, k_row, v_row, imp_row,
+                      tier_row, lh_row, slot, length, token,
+                      table_row=None):
+        with jax.named_scope("kv.ring_relayout"):
+            if hot_window:
+                ring_pos, valid = ring_position_map(length[None],
+                                                    hot_window)
+                dk = pam_if.logical_to_ring(k_row, ring_pos[0], valid[0])
+                dv = pam_if.logical_to_ring(v_row, ring_pos[0], valid[0])
+            else:
+                dk, dv = k_row, v_row
+        with jax.named_scope("kv.slot_install"):
+            cache = cache._replace(
+                k=cache.k.at[:, slot].set(dk),
+                v=cache.v.at[:, slot].set(dv),
+                lengths=cache.lengths.at[slot].set(length))
         if block_size:
             cache = cache._replace(
                 pk=pkv.write_prefill(cache.pk, k_row, table_row,
@@ -691,16 +721,17 @@ def _import_commit_fn(has_pam: bool, block_size: int,
                                      block_size))
         tokens_dev = tokens_dev.at[slot].set(token)
         if has_pam:
-            pam_state = pm.insert_slot_state(
-                pam_state, slot, imp_row, tier_row, lh_row,
-                table_row if block_size else None)
+            with jax.named_scope("pam.place"):
+                pam_state = pm.insert_slot_state(
+                    pam_state, slot, imp_row, tier_row, lh_row,
+                    table_row if block_size else None)
         return cache, pam_state, tokens_dev
 
     if cache_shardings is not None:
         rep = cache_shardings.lengths
-        return jax.jit(commit, donate_argnums=(0, 1, 2),
+        return jax.jit(import_commit, donate_argnums=(0, 1, 2),
                        out_shardings=(cache_shardings, rep, rep))
-    return jax.jit(commit, donate_argnums=(0, 1, 2))
+    return jax.jit(import_commit, donate_argnums=(0, 1, 2))
 
 
 @functools.lru_cache(maxsize=None)
@@ -714,7 +745,8 @@ def _export_gather_fn(block_size: int, hot_window: int = 0):
     snapshot layout is unchanged, so engines with different (or no) hot
     windows interoperate. Dense-only engines just slice the cache."""
     @jax.jit
-    def go(k, v, pk, pv, table_row, tier_row, slot, length):
+    @jax.named_scope("kv.export_gather")
+    def export_gather(k, v, pk, pv, table_row, tier_row, slot, length):
         kc, vc = k[:, slot], v[:, slot]       # (L, Hkv, Smax|W, dh)
         if not block_size:
             return kc, vc
@@ -731,7 +763,7 @@ def _export_gather_fn(block_size: int, hot_window: int = 0):
         hot = (tier_row == HOT)[None, None, :, None]
         return jnp.where(hot, kc, gk), jnp.where(hot, vc, gv)
 
-    return go
+    return export_gather
 
 
 class ServingEngine:
@@ -977,6 +1009,9 @@ class ServingEngine:
         self._m_step_h = h(
             "pam_engine_step_seconds",
             "per-step latency (modeled or wall-clock)", dl).labels(**d)
+        self._m_queue_wait = h(
+            "pam_engine_queue_wait_seconds",
+            "submit to admission, engine clock", dl).labels(**d)
         self._m_active = g(
             "pam_engine_active_slots",
             "slots decoding in the last step", dl).labels(**d)
@@ -1126,7 +1161,8 @@ class ServingEngine:
 
     # ------------------------------------------------------------ lifecycle
     def submit(self, req: Request) -> None:
-        self.requests[req.id] = RequestState(request=req)
+        self.requests[req.id] = RequestState(request=req,
+                                             submit_clock=self.clock)
         self.waiting.append(req.id)
         tr = obs_trace.COLLECTOR
         if tr is not None:
@@ -1167,106 +1203,108 @@ class ServingEngine:
         pool fill, PAM placement and token seeds for every member), so a
         router burst of n same-length prompts costs 2 dispatches, not 2n.
         """
-        # unified admission items: (rid, rs, prompt, s_len, slot,
-        # table_row, start, cow_src) — start = cache-resident prefix
-        # tokens (0 for plain admissions), cow_src = shared tail block
-        # pinned for copy-on-write (-1 = none)
-        admitted: list[tuple] = []
-        free = self._free_slots()
-        while self.waiting and free:
-            rid = self.waiting.popleft()
-            rs = self.requests[rid]
-            prompt = np.asarray(rs.request.prompt, np.int32)
-            s_len = len(prompt)
-            if s_len + rs.request.max_new_tokens > self.scfg.max_len:
-                raise ValueError(f"request {rid} exceeds max_len")
-            table_row = None
-            matched, cow_src = 0, -1
-            if self.allocator is not None:
-                window = s_len + rs.request.max_new_tokens
-                need = self.allocator.blocks_for(window)
-                if need > self.allocator.num_blocks:
-                    # waiting would never help — fail loudly instead of
-                    # starving this and every queued-behind request
-                    raise ValueError(
-                        f"request {rid} needs {need} blocks but the pool "
-                        f"holds {self.allocator.num_blocks}")
-                shared: list[int] = []
-                if self.trie is not None:
-                    # ≥ 1 token is always recomputed (the suffix prefill
-                    # must produce first-token logits), so a full-prompt
-                    # hit caps at s_len - 1
-                    matched, ids = self.trie.lookup(prompt)
-                    matched = min(matched, s_len - 1)
-                    nfull = matched // self.block_size
-                    shared = ids[:nfull]
-                    if matched % self.block_size:
-                        cow_src = ids[nfull]
-                try:
-                    if shared:
-                        # adopt first: the incref shields the matched
-                        # blocks from the eviction pass below
-                        self.allocator.adopt(rid, shared)
-                    if cow_src >= 0:
-                        self.allocator.incref(cow_src)  # CoW-source pin
-                    self._reserve_fresh(need - len(shared))
-                    self.allocator.allocate(rid, window)
-                except OutOfBlocks:
-                    # roll back the adoption (decref) and the CoW pin,
-                    # then wait for freed blocks
-                    if cow_src >= 0:
-                        self.allocator.decref(cow_src)
-                    self.allocator.free(rid)
-                    self.waiting.appendleft(rid)
-                    break
-                table_row = self.allocator.padded_table(
-                    rid, self.scfg.max_len // self.block_size,
-                    self.sentinel)
-                self.peak_occupancy = max(self.peak_occupancy,
-                                          self.allocator.occupancy)
-            slot = free.pop(0)
-            if matched > 0:
-                self.prefix_hits += 1
-                self.cached_prefix_tokens += matched
-                self._m_prefix_hits.inc()
-                self._m_cached_prefix_tokens.inc(matched)
-            if self.chunk and s_len - matched > self.chunk:
-                # chunked admission (PR 8): claim the slot and the full
-                # block window NOW, then fill the prompt one bounded
-                # slice per engine step — interleaved with decode. The
-                # slot is occupied but NOT decode-eligible (PREFILLING)
-                # until the final slice's suffix commit seeds its first
-                # token.
-                rs.status, rs.slot = PREFILLING, slot
-                self.slots[slot] = rid
-                self.rids_host[slot] = rid
-                self._chunking[rid] = ChunkPlan(
-                    rid=rid, slot=slot, start=matched, total=s_len,
-                    budget=self.chunk, cow_src=cow_src)
-                self.chunked_admissions += 1
-                self._m_chunk_adm.inc()
-                tr = obs_trace.COLLECTOR
-                if tr is not None:
-                    tr.begin(rid, "prefill", self.clock,
-                             device=self.name, novel=s_len - matched)
-                continue
-            admitted.append((rid, rs, prompt, s_len, slot, table_row,
-                             matched, cow_src))
+        with obs_trace.span("engine.admit"):
+            # unified admission items: (rid, rs, prompt, s_len, slot,
+            # table_row, start, cow_src) — start = cache-resident prefix
+            # tokens (0 for plain admissions), cow_src = shared tail block
+            # pinned for copy-on-write (-1 = none)
+            admitted: list[tuple] = []
+            free = self._free_slots()
+            while self.waiting and free:
+                rid = self.waiting.popleft()
+                rs = self.requests[rid]
+                prompt = np.asarray(rs.request.prompt, np.int32)
+                s_len = len(prompt)
+                if s_len + rs.request.max_new_tokens > self.scfg.max_len:
+                    raise ValueError(f"request {rid} exceeds max_len")
+                table_row = None
+                matched, cow_src = 0, -1
+                if self.allocator is not None:
+                    window = s_len + rs.request.max_new_tokens
+                    need = self.allocator.blocks_for(window)
+                    if need > self.allocator.num_blocks:
+                        # waiting would never help — fail loudly instead of
+                        # starving this and every queued-behind request
+                        raise ValueError(
+                            f"request {rid} needs {need} blocks but the pool "
+                            f"holds {self.allocator.num_blocks}")
+                    shared: list[int] = []
+                    if self.trie is not None:
+                        # ≥ 1 token is always recomputed (the suffix prefill
+                        # must produce first-token logits), so a full-prompt
+                        # hit caps at s_len - 1
+                        matched, ids = self.trie.lookup(prompt)
+                        matched = min(matched, s_len - 1)
+                        nfull = matched // self.block_size
+                        shared = ids[:nfull]
+                        if matched % self.block_size:
+                            cow_src = ids[nfull]
+                    try:
+                        if shared:
+                            # adopt first: the incref shields the matched
+                            # blocks from the eviction pass below
+                            self.allocator.adopt(rid, shared)
+                        if cow_src >= 0:
+                            self.allocator.incref(cow_src)  # CoW-source pin
+                        self._reserve_fresh(need - len(shared))
+                        self.allocator.allocate(rid, window)
+                    except OutOfBlocks:
+                        # roll back the adoption (decref) and the CoW pin,
+                        # then wait for freed blocks
+                        if cow_src >= 0:
+                            self.allocator.decref(cow_src)
+                        self.allocator.free(rid)
+                        self.waiting.appendleft(rid)
+                        break
+                    table_row = self.allocator.padded_table(
+                        rid, self.scfg.max_len // self.block_size,
+                        self.sentinel)
+                    self.peak_occupancy = max(self.peak_occupancy,
+                                              self.allocator.occupancy)
+                slot = free.pop(0)
+                self._m_queue_wait.observe(self.clock - rs.submit_clock)
+                if matched > 0:
+                    self.prefix_hits += 1
+                    self.cached_prefix_tokens += matched
+                    self._m_prefix_hits.inc()
+                    self._m_cached_prefix_tokens.inc(matched)
+                if self.chunk and s_len - matched > self.chunk:
+                    # chunked admission: claim the slot and the full
+                    # block window NOW, then fill the prompt one bounded
+                    # slice per engine step — interleaved with decode. The
+                    # slot is occupied but NOT decode-eligible (PREFILLING)
+                    # until the final slice's suffix commit seeds its first
+                    # token.
+                    rs.status, rs.slot = PREFILLING, slot
+                    self.slots[slot] = rid
+                    self.rids_host[slot] = rid
+                    self._chunking[rid] = ChunkPlan(
+                        rid=rid, slot=slot, start=matched, total=s_len,
+                        budget=self.chunk, cow_src=cow_src)
+                    self.chunked_admissions += 1
+                    self._m_chunk_adm.inc()
+                    tr = obs_trace.COLLECTOR
+                    if tr is not None:
+                        tr.begin(rid, "prefill", self.clock,
+                                 device=self.name, novel=s_len - matched)
+                    continue
+                admitted.append((rid, rs, prompt, s_len, slot, table_row,
+                                 matched, cow_src))
 
-        # group by NOVEL-length prefill bucket, preserving admission
-        # order. A group with any prefix-cache hit commits through the
-        # batched suffix path (plain members ride along: their zeroed
-        # prefix is masked inside attention — exact); prefix-free groups
-        # keep the PR 1/4 full-prefill path unchanged.
-        groups: dict[int, list[tuple]] = {}
-        for item in admitted:
-            bucket = self._bucket_len(item[3] - item[6])
-            groups.setdefault(bucket, []).append(item)
-        return sum(
-            self._commit_suffix_group(bucket, group)
-            if any(it[6] > 0 for it in group)
-            else self._commit_group(bucket, group)
-            for bucket, group in groups.items())
+            # group by NOVEL-length prefill bucket, preserving admission
+            # order. A group with any prefix-cache hit commits through the
+            # batched suffix path (plain members ride along: their zeroed
+            # prefix is masked inside attention — exact); prefix-free groups
+            # keep the full-prefill path unchanged.
+            groups: dict[int, list[tuple]] = {}
+            for item in admitted:
+                bucket = self._bucket_len(item[3] - item[6])
+                groups.setdefault(bucket, []).append(item)
+            return sum(
+                self._commit_suffix_group(bucket, group)
+                if any(it[6] > 0 for it in group)
+                else self._commit_group(bucket, group)
+                for bucket, group in groups.items())
 
     def _commit_group(self, bucket: int, group: list[tuple]) -> int:
         """Prefill + commit one same-bucket admission group: ONE batched
@@ -1278,8 +1316,9 @@ class ServingEngine:
             padded[i, :s_len] = prompt
             lens[i] = s_len
         pre = self._prefill_for_len(bucket)
-        logits, sub = pre(self.params, jnp.asarray(padded),
-                          jnp.asarray(lens))
+        with obs_trace.span("engine.prefill_dispatch"):
+            logits, sub = pre(self.params, jnp.asarray(padded),
+                              jnp.asarray(lens))
         self.prefill_dispatches += 1
         self._m_prefill_disp.inc()
         slots = np.array([g[4] for g in group], np.int32)
@@ -1288,8 +1327,9 @@ class ServingEngine:
                 jnp.asarray(slots), jnp.asarray(lens), jnp.asarray(rids))
         if self.allocator is not None:
             args += (jnp.asarray(np.stack([g[5] for g in group])),)
-        (self.cache, self.pam_state, self.tokens_dev,
-         first_dev) = self._admit_jit(*args)
+        with obs_trace.span("engine.commit_dispatch"):
+            (self.cache, self.pam_state, self.tokens_dev,
+             first_dev) = self._admit_jit(*args)
         self.admit_dispatches += 1
         self._m_admit_disp.inc()
         for rid, _, _, _, slot, *_rest in group:
@@ -1302,7 +1342,8 @@ class ServingEngine:
             self.novel_prefill_tokens += int(lens.sum())
             for rid, _, prompt, _, _, *_rest in group:
                 self.trie.insert(prompt, self.allocator.table(rid))
-        firsts = np.asarray(first_dev)
+        with obs_trace.span("engine.first_token_readback"):
+            firsts = np.asarray(first_dev)
         for i, (rid, rs, _, _, slot, *_rest) in enumerate(group):
             self._finish_admit(rid, rs, slot, int(firsts[i]))
         return int(lens.sum())
@@ -1399,10 +1440,11 @@ class ServingEngine:
         pre = _suffix_prefill_fn(self.cfg, self.scfg.max_len,
                                  None if self.cache_shardings is None
                                  else self.cache_shardings.lengths)
-        logits, suf_k, suf_v = pre(
-            self.params, jnp.asarray(padded), self.cache.pk,
-            self.cache.pv, jnp.asarray(read_rows), jnp.asarray(starts),
-            jnp.asarray(suf_lens))
+        with obs_trace.span("engine.prefill_dispatch"):
+            logits, suf_k, suf_v = pre(
+                self.params, jnp.asarray(padded), self.cache.pk,
+                self.cache.pv, jnp.asarray(read_rows),
+                jnp.asarray(starts), jnp.asarray(suf_lens))
         self.prefill_dispatches += 1
         self._m_prefill_disp.inc()
         slots = np.array([g[4] for g in group], np.int32)
@@ -1411,12 +1453,13 @@ class ServingEngine:
                                self.scfg.temperature, self.scfg.top_k,
                                self.hot_window, self.scfg.sample_seed,
                                self.cache_shardings)
-        (self.cache, self.pam_state, self.tokens_dev, first_dev) = fn(
-            self.cache, self.pam_state, self.tokens_dev, suf_k, suf_v,
-            logits, jnp.asarray(slots), jnp.asarray(full_lens),
-            jnp.asarray(rids), jnp.asarray(rows), jnp.asarray(bids),
-            jnp.asarray(sids), jnp.asarray(cow_srcs),
-            jnp.asarray(cow_dsts))
+        with obs_trace.span("engine.commit_dispatch"):
+            (self.cache, self.pam_state, self.tokens_dev, first_dev) = fn(
+                self.cache, self.pam_state, self.tokens_dev, suf_k, suf_v,
+                logits, jnp.asarray(slots), jnp.asarray(full_lens),
+                jnp.asarray(rids), jnp.asarray(rows), jnp.asarray(bids),
+                jnp.asarray(sids), jnp.asarray(cow_srcs),
+                jnp.asarray(cow_dsts))
         self.admit_dispatches += 1
         self._m_admit_disp.inc()
         for src in cow_pins:
@@ -1434,7 +1477,8 @@ class ServingEngine:
             # and before any EOS teardown frees the tables
             for rid, _, prompt, _, _, *_rest in group:
                 self.trie.insert(prompt, self.allocator.table(rid))
-        firsts = np.asarray(first_dev)
+        with obs_trace.span("engine.first_token_readback"):
+            firsts = np.asarray(first_dev)
         for i, (rid, rs, _, _, slot, *_rest) in enumerate(group):
             self._finish_admit(rid, rs, slot, int(firsts[i]))
         return int(suf_lens.sum())
@@ -1492,12 +1536,13 @@ class ServingEngine:
         bids, sids = self._suffix_coords(row, begin, t, t)
         fn = _chunk_fill_fn(self.cfg, self.scfg.max_len, cow,
                             self.cache_shardings)
-        self.cache = fn(
-            self.params, self.cache,
-            jnp.asarray(prompt[begin:begin + t][None]),
-            jnp.asarray(row), jnp.int32(begin), jnp.int32(t),
-            jnp.asarray(bids), jnp.asarray(sids),
-            jnp.int32(max(plan.cow_src, 0)), jnp.int32(cow_dst))
+        with obs_trace.span("engine.prefill_dispatch"):
+            self.cache = fn(
+                self.params, self.cache,
+                jnp.asarray(prompt[begin:begin + t][None]),
+                jnp.asarray(row), jnp.int32(begin), jnp.int32(t),
+                jnp.asarray(bids), jnp.asarray(sids),
+                jnp.int32(max(plan.cow_src, 0)), jnp.int32(cow_dst))
         self.prefill_dispatches += 1
         self._m_prefill_disp.inc()
         if cow:
@@ -1513,64 +1558,69 @@ class ServingEngine:
         """One engine iteration: admission (prefill) + one decode step for
         all running sequences — a single fused device dispatch. Returns
         step stats."""
-        t0 = time.perf_counter()
-        prefill_tokens = self._admit() + self._advance_chunks()
+        with obs_trace.span("engine.step"):
+            t0 = time.perf_counter()
+            prefill_tokens = self._admit() + self._advance_chunks()
 
-        # decode-eligible = occupied AND past prefill (a chunking slot
-        # is claimed but PREFILLING until its final slice commits)
-        active_np = np.array([
-            s is not None and self.requests[s].status == RUNNING
-            for s in self.slots])
-        stats: dict[str, Any] = {"prefill_tokens": prefill_tokens,
-                                 "active": int(active_np.sum()),
-                                 "tier_reads": np.zeros(3, np.int64),
-                                 "moved_tokens": 0}
-        if active_np.any():
-            fused = self._get_micro(1)
-            (self.tokens_dev, self.cache, self.pam_state,
-             bufs) = fused(
-                self.params, self.tokens_dev, self.cache, self.pam_state,
-                jnp.asarray(active_np), jnp.asarray(self.rids_host))
-            self.decode_dispatches += 1
-            self.decode_device_steps += 1
-            self._m_decode_disp.inc()
-            self._m_device_steps.inc()
-            if self.mgr:
-                stats["tier_reads"] = np.asarray(
-                    bufs.tier_reads[0], dtype=np.int64)
-                stats["hit_rate"] = float(bufs.hit_rate[0])
-                stats["moved_tokens"] = int(bufs.moved[0])
-            if self.block_size:
-                stats["blocks_touched"] = int(bufs.blocks[0, 0])
-                stats["blocks_window"] = int(bufs.blocks[0, 1])
-                stats["pool_occupancy"] = self.allocator.occupancy
-                self.blocks_touched_total += stats["blocks_touched"]
-                self.blocks_window_total += stats["blocks_window"]
-            stats["batch_lengths"] = np.asarray(bufs.lengths[0])
-            nxt = np.asarray(bufs.tokens[0])
-            self._emit_tokens(nxt, active_np)
-        else:
-            stats["batch_lengths"] = np.asarray(self.cache.lengths)
+            # decode-eligible = occupied AND past prefill (a chunking slot
+            # is claimed but PREFILLING until its final slice commits)
+            active_np = np.array([
+                s is not None and self.requests[s].status == RUNNING
+                for s in self.slots])
+            stats: dict[str, Any] = {"prefill_tokens": prefill_tokens,
+                                     "active": int(active_np.sum()),
+                                     "tier_reads": np.zeros(3, np.int64),
+                                     "moved_tokens": 0}
+            if active_np.any():
+                fused = self._get_micro(1)
+                with obs_trace.span("engine.decode_dispatch"):
+                    (self.tokens_dev, self.cache, self.pam_state,
+                     bufs) = fused(
+                        self.params, self.tokens_dev, self.cache,
+                        self.pam_state, jnp.asarray(active_np),
+                        jnp.asarray(self.rids_host))
+                self.decode_dispatches += 1
+                self.decode_device_steps += 1
+                self._m_decode_disp.inc()
+                self._m_device_steps.inc()
+                with obs_trace.span("engine.readback"):
+                    if self.mgr:
+                        stats["tier_reads"] = np.asarray(
+                            bufs.tier_reads[0], dtype=np.int64)
+                        stats["hit_rate"] = float(bufs.hit_rate[0])
+                        stats["moved_tokens"] = int(bufs.moved[0])
+                    if self.block_size:
+                        stats["blocks_touched"] = int(bufs.blocks[0, 0])
+                        stats["blocks_window"] = int(bufs.blocks[0, 1])
+                        stats["pool_occupancy"] = self.allocator.occupancy
+                        self.blocks_touched_total += stats["blocks_touched"]
+                        self.blocks_window_total += stats["blocks_window"]
+                    stats["batch_lengths"] = np.asarray(bufs.lengths[0])
+                    nxt = np.asarray(bufs.tokens[0])
+                with obs_trace.span("engine.emit"):
+                    self._emit_tokens(nxt, active_np)
+            else:
+                stats["batch_lengths"] = np.asarray(self.cache.lengths)
 
-        # --- timing: modeled or wall-clock --------------------------------
-        if self.latency_model is not None:
-            dt = float(self.latency_model(stats))
-        else:
-            dt = time.perf_counter() - t0
-        self.clock += dt
-        if not prefill_tokens:
-            # load signal: steady DECODE latency only — admission steps
-            # carry a prefill spike that would whipsaw router/balancer
-            # cost comparisons (prefill is priced separately there)
-            self.last_step_time = dt
-            self.last_step_stats = stats
-        if active_np.any():
-            self.busy_time += dt
-        stats["step_time_s"] = dt
-        self._stamp_times()
-        self.steps += 1
-        self._observe_step(stats, dt)
-        return stats
+            # --- timing: modeled or wall-clock ----------------------------
+            if self.latency_model is not None:
+                dt = float(self.latency_model(stats))
+            else:
+                dt = time.perf_counter() - t0
+            self.clock += dt
+            if not prefill_tokens:
+                # load signal: steady DECODE latency only — admission steps
+                # carry a prefill spike that would whipsaw router/balancer
+                # cost comparisons (prefill is priced separately there)
+                self.last_step_time = dt
+                self.last_step_stats = stats
+            if active_np.any():
+                self.busy_time += dt
+            stats["step_time_s"] = dt
+            self._stamp_times()
+            self.steps += 1
+            self._observe_step(stats, dt)
+            return stats
 
     def _emit_tokens(self, nxt: np.ndarray, active: np.ndarray) -> None:
         for slot, rid in enumerate(self.slots):
@@ -1658,10 +1708,12 @@ class ServingEngine:
             for slot, _ in pairs:
                 active_np[slot] = True
             fused = self._get_micro(k)
-            (self.tokens_dev, self.cache, self.pam_state,
-             bufs) = fused(
-                self.params, self.tokens_dev, self.cache, self.pam_state,
-                jnp.asarray(active_np), jnp.asarray(self.rids_host))
+            with obs_trace.span("engine.decode_dispatch"):
+                (self.tokens_dev, self.cache, self.pam_state,
+                 bufs) = fused(
+                    self.params, self.tokens_dev, self.cache,
+                    self.pam_state, jnp.asarray(active_np),
+                    jnp.asarray(self.rids_host))
             self.decode_dispatches += 1
             self.decode_device_steps += k
             self._m_decode_disp.inc()
@@ -1695,15 +1747,16 @@ class ServingEngine:
         post-EOS micro-steps were frozen on device and are skipped."""
         bufs, pairs, k, prefill_tokens = rec
         eos = self.scfg.eos_token
-        toks = np.asarray(bufs.tokens)              # blocks until done
-        reads = np.asarray(bufs.tier_reads, dtype=np.int64)
-        moved = np.asarray(bufs.moved)
-        lens = np.asarray(bufs.lengths)
-        hits = np.asarray(bufs.hit_rate)
-        if self.block_size:
-            blocks = np.asarray(bufs.blocks)
-            self.blocks_touched_total += int(blocks[:, 0].sum())
-            self.blocks_window_total += int(blocks[:, 1].sum())
+        with obs_trace.span("engine.readback"):
+            toks = np.asarray(bufs.tokens)          # blocks until done
+            reads = np.asarray(bufs.tier_reads, dtype=np.int64)
+            moved = np.asarray(bufs.moved)
+            lens = np.asarray(bufs.lengths)
+            hits = np.asarray(bufs.hit_rate)
+            if self.block_size:
+                blocks = np.asarray(bufs.blocks)
+                self.blocks_touched_total += int(blocks[:, 0].sum())
+                self.blocks_window_total += int(blocks[:, 1].sum())
         if self.latency_model is None:
             wall = time.perf_counter()
             dt_wall = (wall - self._wall_anchor) / k

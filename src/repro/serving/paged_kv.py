@@ -449,7 +449,9 @@ def write_prefill(pool: jax.Array, kv: jax.Array,
     past the prompt are overwritten later by per-step appends; unmapped
     entries land in the sentinel block.
     """
-    return pool.at[:, table_row].set(sequence_to_blocks(kv, block_size))
+    with jax.named_scope("kv.prefill_write"):
+        return pool.at[:, table_row].set(sequence_to_blocks(kv,
+                                                            block_size))
 
 
 def copy_block(pool: jax.Array, src: jax.Array, dst: jax.Array) -> jax.Array:
@@ -461,7 +463,8 @@ def copy_block(pool: jax.Array, src: jax.Array, dst: jax.Array) -> jax.Array:
     trie is never written through a shared mapping — the publisher keeps
     appending into the original, the sharer diverges in its own copy.
     """
-    return pool.at[:, dst].set(pool[:, src])
+    with jax.named_scope("kv.cow_copy"):
+        return pool.at[:, dst].set(pool[:, src])
 
 
 def gather_logical(pool: jax.Array, block_table: jax.Array) -> jax.Array:

@@ -205,47 +205,49 @@ def observe_update(cfg: PAMManagerConfig, state: PAMState,
                    participate: jax.Array) -> PAMState:
     """After a decode step: EMA update + hot append + capacity cascade
     + (every interval) Algorithm 2."""
-    B, Smax = state.importance.shape
-    valid = jnp.arange(Smax)[None, :] < lengths[:, None]
+    with jax.named_scope("pam.observe"):
+        B, Smax = state.importance.shape
+        valid = jnp.arange(Smax)[None, :] < lengths[:, None]
 
-    imp = imp_mod.update_importance(state.importance,
-                                    jnp.where(valid, scores, 0.0),
-                                    lam=cfg.lam)
-    # new token (at index lengths-1 after the model appended) -> HOT,
-    # seeded with the current max importance (recency prior).
-    bidx = jnp.arange(B)
-    new_pos = jnp.maximum(lengths - 1, 0)
-    tier = state.tier.at[bidx, new_pos].set(HOT)
-    imp = imp.at[bidx, new_pos].set(
-        jnp.maximum(imp[bidx, new_pos], jnp.max(imp, axis=-1)))
+        imp = imp_mod.update_importance(state.importance,
+                                        jnp.where(valid, scores, 0.0),
+                                        lam=cfg.lam)
+        # new token (at index lengths-1 after the model appended) -> HOT,
+        # seeded with the current max importance (recency prior).
+        bidx = jnp.arange(B)
+        new_pos = jnp.maximum(lengths - 1, 0)
+        tier = state.tier.at[bidx, new_pos].set(HOT)
+        imp = imp.at[bidx, new_pos].set(
+            jnp.maximum(imp[bidx, new_pos], jnp.max(imp, axis=-1)))
 
-    if cfg.use_tiering:
-        # capacity cascade: demote least-important over-capacity tokens
-        tier = _enforce_capacity(imp, tier, valid, HOT,
-                                 cfg.hot_capacity, WARM)
-        tier = _enforce_capacity(imp, tier, valid, WARM,
-                                 cfg.warm_capacity, COLD)
+        if cfg.use_tiering:
+            # capacity cascade: demote least-important over-capacity tokens
+            tier = _enforce_capacity(imp, tier, valid, HOT,
+                                     cfg.hot_capacity, WARM)
+            tier = _enforce_capacity(imp, tier, valid, WARM,
+                                     cfg.warm_capacity, COLD)
 
-        def run_sched(im, ti, va):
-            new_t, moved, _ = scheduling.schedule_kv(im, ti, va,
-                                                     cfg.schedule)
-            return new_t, jnp.sum(moved)
+            def run_sched(im, ti, va):
+                new_t, moved, _ = scheduling.schedule_kv(im, ti, va,
+                                                         cfg.schedule)
+                return new_t, jnp.sum(moved)
 
-        def maybe_schedule(ti):
-            new_t, moved = jax.vmap(run_sched)(imp, ti, valid)
-            return new_t, jnp.sum(moved)
+            def maybe_schedule(ti):
+                new_t, moved = jax.vmap(run_sched)(imp, ti, valid)
+                return new_t, jnp.sum(moved)
 
-        do = (state.step + 1) % cfg.schedule_interval == 0
-        tier, moved = jax.lax.cond(
-            do, maybe_schedule,
-            lambda ti: (ti, jnp.zeros((), jnp.int32)), tier)
-    else:
-        moved = jnp.zeros((), jnp.int32)
+            do = (state.step + 1) % cfg.schedule_interval == 0
+            with jax.named_scope("pam.schedule"):
+                tier, moved = jax.lax.cond(
+                    do, maybe_schedule,
+                    lambda ti: (ti, jnp.zeros((), jnp.int32)), tier)
+        else:
+            moved = jnp.zeros((), jnp.int32)
 
-    return PAMState(importance=imp, tier=tier, step=state.step + 1,
-                    moved_tokens=state.moved_tokens + moved,
-                    last_hot=participate,
-                    block_table=state.block_table)
+        return PAMState(importance=imp, tier=tier, step=state.step + 1,
+                        moved_tokens=state.moved_tokens + moved,
+                        last_hot=participate,
+                        block_table=state.block_table)
 
 
 def place_prefill_state(cfg: PAMManagerConfig, state: PAMState,
@@ -255,23 +257,25 @@ def place_prefill_state(cfg: PAMManagerConfig, state: PAMState,
     §4.3): tail -> HOT, middle -> DDR, head -> SSD. ``table_row``
     ((nb,) physical block ids from the host allocator, sentinel-padded)
     installs the sequence's paged-KV block table in the same dispatch."""
-    Smax = state.importance.shape[1]
-    idx = jnp.arange(Smax)
-    valid = idx < length
-    dist = jnp.maximum(length - 1 - idx, 0)
-    tier = jnp.where(dist < cfg.hot_capacity, HOT,
-                     jnp.where(dist < cfg.hot_capacity
-                               + cfg.warm_capacity, WARM, COLD))
-    imp = jnp.where(valid, 1.0 / (1.0 + dist.astype(jnp.float32)), 0.0)
-    state = state._replace(
-        importance=state.importance.at[slot].set(imp),
-        tier=state.tier.at[slot].set(tier.astype(jnp.int32)),
-        last_hot=state.last_hot.at[slot].set(False),
-    )
-    if table_row is not None:
+    with jax.named_scope("pam.place"):
+        Smax = state.importance.shape[1]
+        idx = jnp.arange(Smax)
+        valid = idx < length
+        dist = jnp.maximum(length - 1 - idx, 0)
+        tier = jnp.where(dist < cfg.hot_capacity, HOT,
+                         jnp.where(dist < cfg.hot_capacity
+                                   + cfg.warm_capacity, WARM, COLD))
+        imp = jnp.where(valid, 1.0 / (1.0 + dist.astype(jnp.float32)),
+                        0.0)
         state = state._replace(
-            block_table=state.block_table.at[slot].set(table_row))
-    return state
+            importance=state.importance.at[slot].set(imp),
+            tier=state.tier.at[slot].set(tier.astype(jnp.int32)),
+            last_hot=state.last_hot.at[slot].set(False),
+        )
+        if table_row is not None:
+            state = state._replace(
+                block_table=state.block_table.at[slot].set(table_row))
+        return state
 
 
 def extract_slot_state(state: PAMState, slot) -> tuple[jax.Array, ...]:
