@@ -18,7 +18,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import online_softmax as osm
-from repro.kernels.flash_attention import flash_attention as _flash_attention
+from repro.kernels.flash_attention import LANES
+from repro.kernels.flash_attention import flash_prefill as _flash_prefill
 from repro.kernels.flash_decode import flash_decode as _flash_decode
 from repro.kernels.flash_decode import flash_decode_paged as _flash_decode_paged
 from repro.kernels.ssd_scan import ssd_scan as _ssd_scan
@@ -28,16 +29,21 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
-                                             "block_k", "interpret"))
-def fused_attention(q, k, v, *, causal=True, scale=None, block_q=128,
-                    block_k=128, interpret=None):
-    """Prefill/train attention. q:(B,H,S,d), k/v:(B,H_kv,S,d) -> (B,H,S,d)."""
+def prefill_kernel_applies(head_dim: int) -> bool:
+    """Whether prefill attention at this head width takes ``flash_prefill``
+    compiled for the chip: on a TPU, with heads a whole number of lanes
+    wide (read in place in the projections' layout)."""
+    return _on_tpu() and head_dim % LANES == 0
+
+
+def prefill_attention(q, k, v, lens=None, *, causal=True, interpret=None):
+    """Prefill and training attention through the blocked flash kernel.
+    q: (B, S, H, d); k/v: (B, S, H_kv, d); lens: optional (B,) live
+    lengths of bucket-padded prompts. Returns (B, S, H, d); under
+    ``causal`` the rows at or past ``lens`` are dead and read zero."""
     if interpret is None:
         interpret = not _on_tpu()
-    return _flash_attention(q, k, v, causal=causal, scale=scale,
-                            block_q=block_q, block_k=block_k,
-                            interpret=interpret)
+    return _flash_prefill(q, k, v, lens, causal=causal, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("kv_len", "scale", "block_s",

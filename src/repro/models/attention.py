@@ -2,7 +2,7 @@
 
 Train path uses memory-friendly q-chunked attention (peak intermediate
 (B, H, chunk, S) instead of (B, H, S, S)); on TPU the Pallas
-``fused_attention`` kernel replaces it via the ``use_kernel`` flag.
+``flash_prefill`` kernel replaces it via the ``use_kernel`` flag.
 
 Decode attention is injectable: the serving/distributed layer passes a
 ``decode_attn_fn`` (e.g. PAMattention over tier pools or the shard_map
@@ -159,10 +159,7 @@ def attention_train(p: AttnParams, x: jax.Array, *, n_heads: int, n_kv: int,
                            rope_theta, rms_eps)
     if use_kernel:
         from repro.kernels import ops as kops
-        out = kops.fused_attention(jnp.moveaxis(q, 2, 1),
-                                   jnp.moveaxis(k, 2, 1),
-                                   jnp.moveaxis(v, 2, 1), causal=causal)
-        out = jnp.moveaxis(out, 1, 2)
+        out = kops.prefill_attention(q, k, v, causal=causal)
     elif perf_flags.enabled("sp_attn"):
         out = sp_attention(q, k, v, causal=causal)
     else:
@@ -174,14 +171,26 @@ def attention_train(p: AttnParams, x: jax.Array, *, n_heads: int, n_kv: int,
 def attention_prefill(p: AttnParams, x: jax.Array, *, n_heads: int,
                       n_kv: int, d_head: int, causal: bool,
                       rope_theta: float, rms_eps: float,
-                      q_chunk: int = 512):
+                      q_chunk: int = 512, true_len: jax.Array | None = None,
+                      use_kernel: bool = False):
     """Like ``attention_train`` but also returns the roped K/V in cache
-    layout (B, Hkv, S, dh) so serving can seed the decode cache."""
+    layout (B, Hkv, S, dh) so serving can seed the decode cache.
+
+    ``use_kernel`` takes the Pallas ``flash_prefill`` kernel in place of
+    ``chunked_attention``; it skips the work of the causal blocks above
+    the diagonal and, given the (B,) ``true_len`` of prompts right-padded
+    to a bucket, of every block at or past it. Rows at or past
+    ``true_len`` are dead (``transformer.prefill``) and may then read 0."""
     B, S, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     q, k, v = _project_qkv(p, x, positions, n_heads, n_kv, d_head,
                            rope_theta, rms_eps)
-    out = chunked_attention(q, k, v, causal=causal, chunk=q_chunk)
+    if use_kernel:
+        from repro.kernels import ops as kops
+        out = kops.prefill_attention(q, k, v, true_len if causal else None,
+                                     causal=causal)
+    else:
+        out = chunked_attention(q, k, v, causal=causal, chunk=q_chunk)
     out = out.reshape(B, S, n_heads * d_head)
     out = jnp.einsum("bse,ed->bsd", out, p.wo)
     return out, jnp.moveaxis(k, 2, 1), jnp.moveaxis(v, 2, 1)

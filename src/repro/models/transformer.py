@@ -271,7 +271,7 @@ def loss_fn(cfg: ModelConfig, params: Params, batch: dict[str, jax.Array],
 # ============================================================ prefill
 def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
             max_len: int, *, patches: jax.Array | None = None,
-            true_len: jax.Array | None = None
+            true_len: jax.Array | None = None, attn_kernel: bool = False
             ) -> tuple[jax.Array, "DecodeCache"]:
     """Batched prompt processing (the paper's NPU prefill phase, §4.3):
     one parallel pass that returns next-token logits AND a filled decode
@@ -287,7 +287,13 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
     logits are taken at position ``true_len - 1`` and cache lengths are set
     to ``true_len``, so stale padded K/V past it is dead and overwritten by
     subsequent decode appends. Only valid for positional-cache families
-    (attention); SSM/hybrid running state would absorb the padding."""
+    (attention); SSM/hybrid running state would absorb the padding.
+
+    ``attn_kernel`` (static) runs the GQA attention layers of the
+    attention families through the Pallas flash kernel
+    (``attention_prefill(use_kernel=True)``), which skips the dead
+    positions' work; MLA and the hybrid's shared attention keep their jnp
+    paths."""
     B, S = tokens.shape
     with jax.named_scope("model.embed"):
         x = params["embed"][tokens]
@@ -324,7 +330,9 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
                     _attn_params(layer), hn, n_heads=cfg.n_heads,
                     n_kv=cfg.n_kv_heads, d_head=cfg.head_dim,
                     causal=cfg.causal, rope_theta=cfg.rope_theta,
-                    rms_eps=cfg.rms_eps)
+                    rms_eps=cfg.rms_eps,
+                    true_len=None if true_len is None else lens,
+                    use_kernel=attn_kernel)
                 h = h + attn_out
             with jax.named_scope("model.prefill_mlp"):
                 hn = rms_norm(h, layer["ln2"], cfg.rms_eps)

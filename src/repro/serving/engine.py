@@ -122,6 +122,7 @@ from repro.core import pam_interface as pam_if
 from repro.core import tiers as tiers_mod
 from repro.frontend.chunking import ChunkPlan, validate_budget
 from repro.core.tiers import HOT
+from repro.kernels import ops as kops
 from repro.kernels.flash_decode import ring_position_map
 from repro.models import transformer as tf
 from repro.models.config import ModelConfig
@@ -443,19 +444,29 @@ def _fused_decode_fn(cfg: ModelConfig, pcfg: Optional[PAMManagerConfig],
     return jax.jit(run_k, donate_argnums=(1, 2, 3))
 
 
+def _prefill_kernel(cfg: ModelConfig, rep=None) -> bool:
+    """Whether a from-scratch prefill's attention takes the Pallas flash
+    kernel: where the kernel applies (``kops.prefill_kernel_applies``), in
+    an unsharded engine (``rep is None``: GSPMD does not partition a Mosaic
+    call), for a GQA model (dense, or MoE without MLA)."""
+    gqa = cfg.family == "dense" or (cfg.family == "moe" and cfg.mla is None)
+    return gqa and rep is None and kops.prefill_kernel_applies(cfg.head_dim)
+
+
 @functools.lru_cache(maxsize=None)
-def _prefill_fn(cfg: ModelConfig, smax: int, rep=None):
+def _prefill_fn(cfg: ModelConfig, smax: int, rep=None, kernel=False):
     # one jit per (cfg, smax); jax retraces per prompt-bucket shape
     # SSM/hybrid prompts are never padded (bucket == exact length),
     # so the dynamic-length machinery is skipped entirely.
     # Returns LOGITS (not a token): the admission commit samples the
     # first token under the same temperature/top-k/PRNG policy as the
-    # fused decode dispatch.
+    # fused decode dispatch. ``kernel``: ``_prefill_kernel``'s answer.
     exact = cfg.family in ("ssm", "hybrid")
 
     def pre(params, tokens, true_len):
         logits, cache = tf.prefill(cfg, params, tokens, smax,
-                                   true_len=None if exact else true_len)
+                                   true_len=None if exact else true_len,
+                                   attn_kernel=kernel)
         return logits, cache
 
     if rep is not None:
@@ -906,6 +917,10 @@ class ServingEngine:
             self.cache = jax.device_put(self.cache, dev)
             self.pam_state = jax.device_put(self.pam_state, dev)
 
+        self.prefill_kernel = _prefill_kernel(
+            cfg, None if self.cache_shardings is None
+            else self.cache_shardings.lengths)
+
         self.trie: Optional[PrefixTrie] = None
         if scfg.prefix_cache:
             if not self.block_size:
@@ -1050,6 +1065,10 @@ class ServingEngine:
             "pam_engine_chunked_admissions_total",
             "admissions that went through chunked prefill",
             dl).labels(**d)
+        self._m_prefill_kernel = c(
+            "pam_engine_prefill_kernel_total",
+            "prefill dispatches whose attention took the flash kernel",
+            dl).labels(**d)
         self._m_chunk_slices = c(
             "pam_engine_chunk_slices_total",
             "chunked-prefill slice dispatches", dl).labels(**d)
@@ -1156,7 +1175,7 @@ class ServingEngine:
             self._prefill_jit[bucket] = _prefill_fn(
                 self.cfg, self.scfg.max_len,
                 None if self.cache_shardings is None
-                else self.cache_shardings.lengths)
+                else self.cache_shardings.lengths, self.prefill_kernel)
         return self._prefill_jit[bucket]
 
     # ------------------------------------------------------------ lifecycle
@@ -1321,6 +1340,8 @@ class ServingEngine:
                               jnp.asarray(lens))
         self.prefill_dispatches += 1
         self._m_prefill_disp.inc()
+        if self.prefill_kernel:
+            self._m_prefill_kernel.inc()
         slots = np.array([g[4] for g in group], np.int32)
         rids = np.array([g[0] for g in group], np.uint32)
         args = (self.cache, self.pam_state, self.tokens_dev, sub, logits,

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import flash_attention, flash_prefill
 from repro.kernels.ssd_scan import ssd_scan
 from repro.kernels import ops
 
@@ -67,6 +67,55 @@ def test_flash_attention_long_context_stability():
     want = ref.flash_attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("H,Hkv,S,bq,bk,true_len", [
+    (4, 2, 256, 64, 64, None),           # GQA rep 2, causal, 4x4 blocks
+    (10, 2, 192, 64, 64, None),          # GQA rep 5 (the 14B stage's)
+    (4, 2, 200, 64, 128, None),          # S not a multiple of the block
+    (4, 2, 256, 64, 64, (130, 256)),     # a prompt below its bucket
+    (10, 2, 256, 128, 64, (77, 200)),    # rep 5, q blocks and kv blocks skip
+    (4, 2, 200, None, None, None),       # shipped blocks, S past one lane
+    (4, 2, 1000, None, None, (1000, 613)),   # tile and off a power of two
+])
+def test_flash_prefill_matches_chunked(H, Hkv, S, bq, bk, true_len):
+    """The prefill kernel against the served jnp path (``chunked_attention``)
+    at bf16 inputs in the projections' layout, lane-wide heads. Rows at or
+    past ``true_len`` are dead: they are not compared, only kept finite."""
+    from repro.models.attention import chunked_attention
+    B, d = (1, 128) if true_len is None else (len(true_len), 128)
+    key = jax.random.PRNGKey(H * 1000 + S)
+    q, k, v = (jax.random.normal(jax.random.fold_in(key, i), (B, S, h, d),
+                                 jnp.bfloat16)
+               for i, h in enumerate((H, Hkv, Hkv)))
+    lens = None if true_len is None else jnp.asarray(true_len, jnp.int32)
+    blocks = {} if bq is None else dict(block_q=bq, block_k=bk)
+    out = flash_prefill(q, k, v, lens, causal=True, interpret=True, **blocks)
+    want = chunked_attention(q, k, v, causal=True, chunk=64)
+    assert out.shape == want.shape and out.dtype == jnp.bfloat16
+    got, want = np.asarray(out, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    for b in range(B):
+        n = S if true_len is None else true_len[b]
+        np.testing.assert_allclose(got[b, :n], want[b, :n],
+                                   **_tol(jnp.bfloat16))
+
+
+@pytest.mark.parametrize("d_head", [64, 128])   # head-major, lane layout
+def test_attention_train_kernel_matches_chunked(d_head):
+    """The training path's kernel (``use_kernel=True``) reads narrow heads
+    head-major and lane-wide heads in place; both equal the jnp path."""
+    from repro.models.attention import attention_train, init_attn
+    d, H, Hkv, S = 64, 4, 2, 200
+    p = init_attn(jax.random.PRNGKey(d_head), d, H, Hkv, d_head, True,
+                  jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, S, d))
+    kw = dict(n_heads=H, n_kv=Hkv, d_head=d_head, causal=True,
+              rope_theta=1e4, rms_eps=1e-6)
+    got = attention_train(p, x, use_kernel=True, **kw)
+    want = attention_train(p, x, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
 
 
 # --------------------------------------------------------------- flash_decode

@@ -1,5 +1,5 @@
-"""Ahead-of-time compiles of the serving decode kernels for a described
-TPU v5e chip, at qwen3-0.6b's published widths in bf16.
+"""Ahead-of-time compiles of the serving kernels (decode and prefill) for
+a described TPU v5e chip, at qwen3-0.6b's published widths in bf16.
 
 Nothing runs: the TPU compiler installed with jaxlib lowers and compiles
 each kernel for a chip that is described, not attached, and raises what
@@ -14,6 +14,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
+from repro.kernels.flash_attention import flash_prefill
 from repro.kernels.flash_decode import flash_decode, flash_decode_paged
 from repro.models.config import get_config
 
@@ -93,3 +94,30 @@ def test_paged_masked_decode_attention_compiles(one_chip, monkeypatch):
             q, kc, vc, pk, pv, bt, hot, pgd, lens, use_kernel=True)
 
     _compiled_text(attn, q, ring, ring, pool, pool, table, mask, mask, lens)
+
+
+def test_flash_prefill_compiles(one_chip):
+    """The prefill kernel at the blocks it ships with, for a group of two
+    prompts in the 8,192 bucket with their live lengths."""
+    bucket = 8192
+    q = _spec((2, bucket, H, D), BF16, one_chip)
+    kv = _spec((2, bucket, HKV, D), BF16, one_chip)
+    lens = _spec((2,), jnp.int32, one_chip)
+    text = _compiled_text(flash_prefill, q, kv, kv, lens)
+    assert "flash_prefill" in text
+
+
+@pytest.mark.parametrize("name,batch,seq,live", [
+    ("qwen3-0.6b", 2, 1000, True),    # an unbucketed prompt, off the tiles
+    ("minicpm-2b", 1, 2048, False),   # 64-wide heads, read head-major
+])
+def test_flash_prefill_odd_shapes_compile(one_chip, name, batch, seq, live):
+    """The prefill kernel at the shipped blocks where the sequence is not
+    a multiple of them, and where heads are narrower than a lane tile (the
+    training path of such models)."""
+    cfg = get_config(name)
+    q = _spec((batch, seq, cfg.n_heads, cfg.head_dim), BF16, one_chip)
+    kv = _spec((batch, seq, cfg.n_kv_heads, cfg.head_dim), BF16, one_chip)
+    args = (q, kv, kv) + ((_spec((batch,), jnp.int32, one_chip),)
+                          if live else ())
+    assert "flash_prefill" in _compiled_text(flash_prefill, *args)
