@@ -49,11 +49,10 @@ def main(argv=None) -> int:
     if devs is None:
         return 2
     import jax
-    from bench import weights
     R.enable_cache()
     rows = []
     for seed in args.seeds:
-        params = weights.make_params(cell.hf, seed)
+        params = R.make_weights(cell, seed)
         jax.block_until_ready(params)
         served, _, _ = R.serve_cell(cell, params, seed, args.seconds,
                                      warm=seed == args.seeds[0])
@@ -63,7 +62,7 @@ def main(argv=None) -> int:
                   setup_phases=served.setup_phases)
             continue
         checks, extra = R.check(
-            cell, lambda s=seed: weights.make_params(cell.hf, s), served,
+            cell, lambda s=seed: R.make_weights(cell, s), served,
             seed, controls=tuple(args.controls))
         row = {"seed": seed,
                "program": {n: checks[n]["value"] for n in NUMBERS},

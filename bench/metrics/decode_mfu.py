@@ -1,6 +1,6 @@
 """Model operations of the traced decode steps over their device time
-times the chip's bf16 peak: matmuls of the active sequences and attention
-over the participating tokens."""
+times the bf16 peak of the cell's chips: matmuls of the active sequences
+and attention over the participating tokens."""
 
 
 def read(run):
@@ -12,4 +12,5 @@ def read(run):
               for s in steps)
     # steps and programs are matched by count over the same window
     ops *= n / len(steps)
-    return 100.0 * ops / (total / 1e9 * run.peak["bf16_flops_per_s"])
+    return 100.0 * ops / (total / 1e9 * run.peak["bf16_flops_per_s"]
+                          * run.chips)

@@ -2,7 +2,8 @@
 time. The work is the K/V of the participating tokens that PAM reads
 from the paged pool (warm and cold tiers), at the configuration's dtype,
 and their QK^T and PV operations; the least time is the larger of bytes
-over peak bandwidth and operations over peak bf16 rate."""
+over peak bandwidth and operations over peak bf16 rate, of the cell's
+chips (kernel time is per device)."""
 
 
 def read(run):
@@ -15,6 +16,6 @@ def read(run):
     paged *= (n / run.hf["num_hidden_layers"]) / len(steps)
     nbytes = paged * run.flops.kv_bytes_per_token(run.hf)
     ops = paged * run.flops.attention_flops_per_token(run.hf)
-    least = max(nbytes / run.peak["hbm_bytes_per_s"],
-                ops / run.peak["bf16_flops_per_s"])
+    least = max(nbytes / (run.peak["hbm_bytes_per_s"] * run.chips),
+                ops / (run.peak["bf16_flops_per_s"] * run.chips))
     return 100.0 * least / (total / 1e9)

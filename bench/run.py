@@ -6,7 +6,9 @@
 A cell (``BENCHMARK.json`` ``workloads``) names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``). The run makes the weights from the
-seed on the device, builds ``EngineSpec`` -> ``ServingEngine`` ->
+seed on the cell's devices (sharded over them when the cell takes
+more than one chip), builds ``EngineSpec`` (``shard`` = the cell's
+chips) -> ``ServingEngine`` ->
 ``ClusterRouter.for_engine`` -> ``AsyncServer`` on the wall clock, warms
 every shape the traffic uses, and then acts as the client for
 ``--seconds``: it submits each request when it falls due, pumps the
@@ -41,6 +43,7 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+import threading  # noqa: E402
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 REPO_ROOT = os.path.dirname(BENCH_DIR)
@@ -136,20 +139,62 @@ class Served:
     grace_end: float
     lateness: list
     window_compiles: int
-    memory_peak: int | None
+    memory_peaks: list      # peak bytes in use of each device
     pool: dict
     setup_phases: dict
     rejected: set
 
 
-def build_server(cell, params, *, devices=None):
+def cell_devices(cell) -> tuple:
+    """The devices a cell runs on: the first ``chips`` of the process."""
+    import jax
+    return tuple(jax.devices()[:cell.chips])
+
+
+def param_shardings(cell):
+    """Where the weights live: None (the default device) for one chip;
+    for a sharded cell, the program's own parameter layout over its
+    decode mesh on the cell's devices, so that the engine finds them in
+    place and copies nothing."""
+    devices = cell_devices(cell)
+    if len(devices) == 1:
+        return None
+    from repro.distributed import pam_shard as psh
+    from repro.distributed import sharding as shd
+    mcfg = spec_mod.model_config(cell.hf, cell.config_name)
+    return shd.param_shardings(mcfg, psh.decode_mesh(tuple(devices)))
+
+
+def make_weights(cell, seed: int):
+    """The cell's weights from ``seed``, made in place on its devices."""
+    from bench import weights
+    return weights.make_params(cell.hf, seed, param_shardings(cell))
+
+
+def bytes_per_device(tree) -> dict:
+    """Bytes of ``tree`` each device holds (its addressable shards)."""
+    import jax
+    out: dict = {}
+    for leaf in jax.tree.leaves(tree):
+        for sh in leaf.addressable_shards:
+            out[sh.device.id] = out.get(sh.device.id, 0) + sh.data.nbytes
+    return out
+
+
+def memory_peaks(devices) -> list:
+    """``peak_bytes_in_use`` of each device (None where not reported)."""
+    return [(d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in devices]
+
+
+def build_server(cell, params, devices):
     from repro.cluster.router import ClusterRouter
     from repro.frontend.server import AsyncServer
     from repro.serving import EngineSpec
     mcfg = spec_mod.model_config(cell.hf, cell.config_name)
     scfg = spec_mod.serving_config(cell.traffic)
-    engine = EngineSpec(model=mcfg, serving=scfg, name="chip0").build(
-        params, devices=devices)
+    engine = EngineSpec(model=mcfg, serving=scfg, shard=len(devices),
+                        name="chip0").build(params, devices=devices)
     return engine, AsyncServer(ClusterRouter.for_engine(engine))
 
 
@@ -174,13 +219,14 @@ def warm_requests(cell, rng):
 
 def serve_cell(cell, params, seed: int, seconds: float, *, trace=False,
                clock=None, warm=True):
-    """Set up, warm (unless this process already ran the cell's shapes),
-    run the window and the grace period. Returns (Served, RunData or
-    None, setup seconds)."""
+    """Set up on the cell's devices, warm (unless this process already
+    ran the cell's shapes), run the window and the grace period. Returns
+    (Served, RunData or None, setup seconds)."""
     import jax
     from bench.client import Client
+    devices = cell_devices(cell)
     phases = {"to_server": time.perf_counter() - T_PROCESS}
-    engine, srv = build_server(cell, params)
+    engine, srv = build_server(cell, params, devices)
     client = Client(srv, engine, instrument=trace)
     rng = np.random.default_rng(seed)
     t = time.perf_counter()
@@ -210,7 +256,7 @@ def serve_cell(cell, params, seed: int, seconds: float, *, trace=False,
     setup_s = t_open - T_PROCESS
     compiles_before = clock.events if clock else 0
 
-    tracer = _Tracer(client, seconds, tr) if trace else None
+    tracer = _Tracer(client, seconds, tr, devices) if trace else None
     grace = float(tr["grace_seconds"])
     if tr["loop"] == "open":
         grace_end = client.open_loop(reqs, seconds, grace,
@@ -224,8 +270,7 @@ def serve_cell(cell, params, seed: int, seconds: float, *, trace=False,
     if tracer is not None:
         tracer.stop()
     window_compiles = (clock.events - compiles_before) if clock else 0
-    dev = jax.devices()[0]
-    peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
+    peaks = memory_peaks(devices)
     pool = {"pool_blocks": engine.allocator.num_blocks,
             "full_blocks": engine.scfg.max_batch * engine.scfg.max_len
             // engine.block_size,
@@ -237,7 +282,7 @@ def serve_cell(cell, params, seed: int, seconds: float, *, trace=False,
                     due={rid: client.due.get(rid, 0.0) for rid in in_scope},
                     window=float(seconds), grace_end=grace_end,
                     lateness=client.lateness,
-                    window_compiles=window_compiles, memory_peak=peak,
+                    window_compiles=window_compiles, memory_peaks=peaks,
                     pool=pool, setup_phases=phases,
                     rejected={r for r in in_scope if srv.records[r].rejected})
     run_data = tracer.run_data(cell) if tracer is not None else None
@@ -247,11 +292,28 @@ def serve_cell(cell, params, seed: int, seconds: float, *, trace=False,
     return served, run_data, setup_s
 
 
-class _Tracer:
-    """Traces a slice of the window (centred, ``trace_seconds`` long)."""
+def profile_options():
+    """What the traced slice records: the device's ops and programs and the
+    client's ``TraceAnnotation`` spans (host level 1), and no Python
+    function calls. JAX's default also traces every Python call, which
+    slows the client inside the slice."""
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    return opts
 
-    def __init__(self, client, seconds, tr):
+
+class _Tracer:
+    """Traces a slice of the window (centred, ``trace_seconds`` long).
+
+    ``stop_trace`` exports the slice, which takes tens of seconds on four
+    chips; it releases the GIL, so it runs on a thread of its own while
+    the client goes on serving, and the slice is read once it is done."""
+
+    def __init__(self, client, seconds, tr, devices):
         self.client = client
+        self.device_ids = [d.id for d in devices]
         span = min(float(tr.get("trace_seconds", 4.0)), seconds)
         self.start_at = max((seconds - span) / 2, 0.0)
         self.stop_at = self.start_at + span
@@ -259,41 +321,70 @@ class _Tracer:
         self.state = "before"
         self.ann = None
         self.trace = None
+        self.stopper = None
+        self.error = None
+        self.export_s = 0.0
+        self.last_tick = None
+        self.longest_tick_gap = 0.0     # of the client, while exporting
 
     def tick(self, now):
         import jax
         if self.state == "before" and now >= self.start_at:
-            jax.profiler.start_trace(self.dir)
+            jax.profiler.start_trace(self.dir,
+                                     profiler_options=profile_options())
             self.ann = jax.profiler.TraceAnnotation("bench.window")
             self.ann.__enter__()
             self.client.recording = True
             self.state = "on"
         elif self.state == "on" and now >= self.stop_at:
             self.stop()
+        elif self.state == "done" and self.stopper.is_alive():
+            self.longest_tick_gap = max(self.longest_tick_gap,
+                                        now - self.last_tick)
+        self.last_tick = now
 
     def stop(self):
-        import jax
         if self.state != "on":
             return
         self.client.recording = False
         self.ann.__exit__(None, None, None)
-        jax.profiler.stop_trace()
+        self.stopper = threading.Thread(target=self._export)
+        self.stopper.start()
         self.state = "done"
-        self.trace = trace_mod.load(self.dir)
-        shutil.rmtree(self.dir, ignore_errors=True)
+
+    def _export(self):
+        import jax
+        t = time.perf_counter()
+        try:
+            jax.profiler.stop_trace()
+        except BaseException as e:      # raised again by ``run_data``
+            self.error = e
+        self.export_s = time.perf_counter() - t
 
     def run_data(self, cell):
-        if self.trace is None:
+        """The traced slice, read once the window and grace are over and
+        its export has ended (a four-chip trace takes tens of seconds to
+        export and to read)."""
+        if self.state != "done":
             raise RuntimeError("the traced slice never started")
+        self.stopper.join()
+        if self.error is not None:
+            raise self.error
+        log(phase="export", seconds=self.export_s,
+            longest_client_gap_s=self.longest_tick_gap)
+        self.trace = trace_mod.load(self.dir, self.device_ids)
+        shutil.rmtree(self.dir, ignore_errors=True)
         c = self.client
         return RunData(cell=cell, hf=cell.hf, trace=self.trace,
                        pumps=list(c.pumps), steps=list(c.steps),
-                       admitted=list(c.admitted))
+                       admitted=list(c.admitted), chips=cell.chips)
 
 
 @dataclasses.dataclass
 class RunData:
-    """What a per-layer reader sees of a traced run."""
+    """What a per-layer reader sees of a traced run. Program and op times
+    are per device (the mean over the cell's devices); a share of a peak
+    divides by one chip's peak times ``chips``."""
     cell: object
     hf: dict
     trace: object
@@ -302,15 +393,18 @@ class RunData:
     admitted: list          # prompt lengths first answered in the slice
     peak: dict = None
     flops: object = flops_mod
+    chips: int = 1
 
     def span(self):
         return trace_mod.window(self.trace)
 
     def module_time(self, needle):
-        return trace_mod.time_of(self.trace.modules, needle, *self.span())
+        return trace_mod.device_time_of(self.trace, "modules", needle,
+                                        *self.span())
 
     def op_time(self, needle):
-        return trace_mod.time_of(self.trace.ops, needle, *self.span())
+        return trace_mod.device_time_of(self.trace, "ops", needle,
+                                        *self.span())
 
     def _in_flight(self):
         """Pump spans merged across the short gaps between pumps: the
@@ -330,10 +424,16 @@ class RunData:
         return sum(e - s for s, e in self._in_flight())
 
     def busy_in_flight_ns(self):
+        """Device-busy time while a request was in flight, the mean over
+        the devices."""
         t0, t1 = self.span()
-        merged = trace_mod.union(self.trace.ops, t0, t1)
-        return sum(trace_mod.covered(merged, s, e)
-                   for s, e in self._in_flight())
+        flight = self._in_flight()
+        busy = []
+        for plane in self.trace.planes:
+            merged = trace_mod.union(plane.ops, t0, t1)
+            busy.append(sum(trace_mod.covered(merged, s, e)
+                            for s, e in flight))
+        return trace_mod.mean(busy)
 
 
 # -------------------------------------------------------------- correctness
@@ -495,34 +595,45 @@ def require_chips(chips: int):
 
 def run(cell, seed: int, seconds: float, trace: bool, devs) -> dict:
     import jax
-    from bench import weights
     clock = CompileClock().install()
     cache_dir = enable_cache()
     peak_table = load_peaks(devs[0].device_kind)
-    params = weights.make_params(cell.hf, seed)
+    devices = cell_devices(cell)
+    params = make_weights(cell, seed)
     jax.block_until_ready(params)
+    log(phase="weights", bytes_per_device=bytes_per_device(params),
+        peak_bytes_per_device=memory_peaks(devices))
     served, run_data, setup_s = serve_cell(cell, params, seed, seconds,
                                            trace=trace, clock=clock)
     lat = served.lateness
+    tt, _ = stats.ttfts(served.due, served.times, served.window,
+                        served.grace_end)
     log(phase="window", cell=cell.name, seed=seed, seconds=seconds,
         trace=trace, setup_s=setup_s, compile_cache=cache_dir,
         setup_compile_s=clock.seconds, cache_hits=clock.cache_hits,
         window_compiles=served.window_compiles,
         generator_late_p50_ms=1e3 * float(np.median(lat)) if lat else 0.0,
         generator_late_max_ms=1e3 * float(np.max(lat)) if lat else 0.0,
+        memory_peak_bytes_per_device=served.memory_peaks,
         in_scope=len(served.in_scope), finished=len(served.finished),
         grace_end_s=served.grace_end, setup_phases=served.setup_phases,
+        ttft_p50_ms=1e3 * stats.percentile(tt, 50) if tt else None,
+        ttft_p95_ms=1e3 * stats.percentile(tt, 95) if tt else None,
         **served.pool)
     checks, extra = check(cell, lambda: params, served, seed)
     log(phase="check", **extra)
+    peaks = [p for p in served.memory_peaks if p is not None]
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-              "count": len(devs), "memory_peak_bytes": served.memory_peak}
+              "count": len(devices),
+              "memory_peak_bytes": max(peaks) if peaks else None}
     result = {"correct": is_correct(checks),
               "attempted": len(served.in_scope),
               "failed": failed_count(cell, served)}
     if trace:
         run_data.peak = peak_table
         t0, t1 = run_data.span()
+        log(phase="trace", planes=[p.name for p in run_data.trace.planes],
+            ops=[len(p.ops) for p in run_data.trace.planes])
         device["busy_s"] = trace_mod.busy_ns(run_data.trace, t0, t1) / 1e9
         device["window_s"] = (t1 - t0) / 1e9
         result["metrics"] = per_layer(cell, run_data)

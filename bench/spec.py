@@ -2,8 +2,8 @@
 
 A cell names a configuration (``configs/<config>.json``, the file the
 BENCHMARK entry gives) and a traffic mix (``traffic/<traffic>.json``). Its
-correctness limit is ``limits/<cell>.json``. Nothing here knows any cell
-by name.
+correctness limit is ``limits/<cell>.json``; its model family is
+``families/<model_type>.py``. Nothing here knows any cell by name.
 """
 
 from __future__ import annotations
@@ -60,19 +60,10 @@ def load_cell(name: str, root: str = REPO_ROOT) -> Cell:
 
 
 def model_config(hf: dict, name: str):
-    """The serving program's ``ModelConfig`` for a Qwen3 config file."""
-    from repro.models.config import ModelConfig
-    if hf.get("model_type") != "qwen3":
-        raise ValueError(f"{name}: model_type {hf.get('model_type')!r} has "
-                         f"no mapping onto the serving program")
-    return ModelConfig(
-        name=name, family="dense", n_layers=hf["num_hidden_layers"],
-        d_model=hf["hidden_size"], n_heads=hf["num_attention_heads"],
-        n_kv_heads=hf["num_key_value_heads"], d_ff=hf["intermediate_size"],
-        vocab=hf["vocab_size"], d_head=hf["head_dim"], qk_norm=True,
-        rope_theta=float(hf["rope_theta"]), rms_eps=float(hf["rms_norm_eps"]),
-        tie_embeddings=bool(hf["tie_word_embeddings"]),
-        dtype=hf["torch_dtype"])
+    """The serving program's ``ModelConfig`` for a config file, from its
+    family (``bench/families/<model_type>.py``)."""
+    from bench import families
+    return families.of(hf).model_config(hf, name)
 
 
 def serving_config(traffic: dict):

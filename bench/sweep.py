@@ -3,10 +3,12 @@
 
     python bench/sweep.py --workload <cell> --seconds <s> --rates 2 3 4 5
 
-One process: the weights once, then for each rate a fresh server, the
+One process: the weights once (on the cell's chips, sharded as the
+cell's runs make them), then for each rate a fresh server, the
 cell's warm-up and a window at that rate (the cell's traffic file with
 only ``rate_rps`` changed). Prints one JSON line per rate: offered and
-served tokens, the TTFT median and 95th percentile, and the requests
+served tokens, the TTFT median, 95th percentile, mean and every
+request's TTFT, and the requests
 still unserved when the window closed (a backlog that grows with the
 window means the rate is above what the server sustains). The cell's
 rate in its traffic file is set once from this, at about four fifths of
@@ -45,9 +47,8 @@ def main(argv=None) -> int:
     if devs is None:
         return 2
     import jax
-    from bench import weights
     R.enable_cache()
-    params = weights.make_params(cell.hf, args.seed)
+    params = R.make_weights(cell, args.seed)
     jax.block_until_ready(params)
     for rate in args.rates:
         tr = dict(cell.traffic,
@@ -66,6 +67,8 @@ def main(argv=None) -> int:
               out_tok_s=stats.window_tokens(served.times, w) / w,
               ttft_p50_ms=1e3 * stats.percentile(tt, 50),
               ttft_p95_ms=1e3 * stats.percentile(tt, 95),
+              ttft_mean_ms=1e3 * float(np.mean(tt)),
+              ttfts_ms=sorted(round(1e3 * t, 1) for t in tt),
               itl_p95_ms=1e3 * stats.percentile(
                   stats.window_gaps(served.times, w), 95),
               unfinished_at_close=len(late), missing=missing,

@@ -11,7 +11,8 @@ from bench import trace as T
 from bench.run import RunData, load_metric
 
 MS = 1_000_000
-HF = {"num_hidden_layers": 2, "hidden_size": 128, "intermediate_size": 256,
+HF = {"model_type": "qwen3", "num_hidden_layers": 2, "hidden_size": 128,
+      "intermediate_size": 256,
       "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
       "vocab_size": 512, "torch_dtype": "bfloat16"}
 PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
@@ -74,8 +75,9 @@ def test_admission_is_prefill_and_commit_per_commit():
 
 def test_admission_programs_that_overlap_count_once():
     run = run_of()
-    mods = run.trace.modules + [("jit_pre(3)", 25 * MS, 40 * MS)]
-    run.trace = T.from_events(run.trace.ops, mods, run.trace.host)
+    plane = run.trace.planes[0]
+    mods = plane.modules + [("jit_pre(3)", 25 * MS, 40 * MS)]
+    run.trace = T.from_events(plane.ops, mods, run.trace.host)
     assert load_metric("admission_ms")(run) == pytest.approx(36.0)
 
 

@@ -1,6 +1,6 @@
 """Reduction of a trace to busy time, kernel time and labelled idle gaps."""
 
-import _tiny  # noqa: F401
+import _tiny
 from bench import trace as T
 
 MS = 1_000_000
@@ -36,10 +36,10 @@ def test_busy_is_the_union_of_ops():
 def test_kernel_and_program_time_by_name():
     tr = small_trace()
     t0, t1 = T.window(tr)
-    assert T.time_of(tr.ops, "flash_decode_paged", t0, t1) == (
+    assert T.time_of(tr.planes[0].ops, "flash_decode_paged", t0, t1) == (
         2 * MS + MS + MS // 2, 2)
-    assert T.time_of(tr.modules, "jit_run_k", t0, t1) == (5 * MS, 2)
-    assert T.time_of(tr.modules, "jit_pre", t0, t1) == (MS // 2, 1)
+    assert T.time_of(tr.planes[0].modules, "jit_run_k", t0, t1) == (5 * MS, 2)
+    assert T.time_of(tr.planes[0].modules, "jit_pre", t0, t1) == (MS // 2, 1)
 
 
 def test_idle_gaps_are_labelled_by_the_host_span():
@@ -58,13 +58,16 @@ def test_idle_gaps_are_labelled_by_the_host_span():
 
 
 def test_host_spans_of_a_recorded_trace(tmp_path):
-    """The client's span names survive a real profiler trace (CPU)."""
+    """The client's span names survive a real profiler trace (CPU), with
+    the options the benchmark traces under (no Python tracer)."""
     import jax
     import jax.numpy as jnp
+    from bench import run as R
     f = jax.jit(lambda x: jnp.sin(x).sum())
     x = jnp.ones((64,))
     f(x).block_until_ready()
-    jax.profiler.start_trace(str(tmp_path))
+    jax.profiler.start_trace(str(tmp_path),
+                             profiler_options=R.profile_options())
     with jax.profiler.TraceAnnotation("bench.window"):
         for _ in range(3):
             with jax.profiler.TraceAnnotation("pump"):
@@ -75,3 +78,31 @@ def test_host_spans_of_a_recorded_trace(tmp_path):
     assert names.count("pump") == 3 and names.count("bench.window") == 1
     t0, t1 = T.window(tr)
     assert all(t0 <= s and e <= t1 for n, s, e in tr.host if n == "pump")
+
+
+def test_traced_run_exports_its_slice_beside_the_client(monkeypatch):
+    """A traced run of the toy cell (CPU): the profiler's export runs on a
+    thread of its own, not the client's, and the slice is read after it,
+    with the client's spans in it."""
+    import threading
+
+    import jax
+    from bench import run as R
+    from bench import weights
+    stopped_on = []
+    real_stop = jax.profiler.stop_trace
+
+    def stop_trace():
+        stopped_on.append(threading.current_thread())
+        real_stop()
+
+    monkeypatch.setattr(jax.profiler, "stop_trace", stop_trace)
+    cell = _tiny.cell(requests=64)     # traffic that outlasts the window
+    params = weights.make_params(cell.hf, 11)
+    served, run, _ = R.serve_cell(cell, params, 11, 2.0, trace=True)
+    assert len(stopped_on) == 1
+    assert stopped_on[0] is not threading.main_thread()
+    names = [h[0] for h in run.trace.host]
+    assert names.count("bench.window") == 1 and "pump" in names
+    assert R.load_metric("host_pump_ms")(run) > 0
+    assert served.finished

@@ -1,8 +1,10 @@
 """Random weights made on the device from the seed, in one jitted call.
 
-The layout is the serving program's parameter tree (layers stacked on a
-leading axis); the reference reads the same tree, which the benchmark
-makes and the program only receives.
+The layout is the serving program's parameter tree, as the configuration's
+family gives it (``bench/families/<model_type>.py``: ``shapes`` and
+``leaf``); the reference reads the same tree, which the benchmark makes
+and the program only receives. A sharded cell's tree is made in its
+shardings, so that each device computes and holds only its own part.
 """
 
 from __future__ import annotations
@@ -11,6 +13,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from bench import families
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -24,39 +28,13 @@ def seed_key(seed: int) -> jax.Array:
 
 
 def shapes(hf: dict) -> dict:
-    """Leaf shapes of the tree for a Qwen3 config file."""
-    d, f = hf["hidden_size"], hf["intermediate_size"]
-    h, kv, dh = (hf["num_attention_heads"], hf["num_key_value_heads"],
-                 hf["head_dim"])
-    n_layers, vocab = hf["num_hidden_layers"], hf["vocab_size"]
-    L = (n_layers,)
-    tree = {
-        "embed": (vocab, d),
-        "final_norm": (d,),
-        "layers": {
-            "ln1": L + (d,), "ln2": L + (d,),
-            "attn": {"wq": L + (d, h * dh), "wk": L + (d, kv * dh),
-                     "wv": L + (d, kv * dh), "wo": L + (h * dh, d),
-                     "q_norm": L + (dh,), "k_norm": L + (dh,)},
-            "mlp": {"gate": L + (d, f), "up": L + (d, f), "down": L + (f, d)},
-        },
-    }
-    if not hf["tie_word_embeddings"]:
-        tree["lm_head"] = (d, vocab)
-    return tree
+    """Leaf shapes of the tree for config ``hf``."""
+    return families.of(hf).shapes(hf)
 
 
-def _leaf(key, path: str, shape: tuple, dtype):
-    name = path.rsplit("/", 1)[-1]
-    if name in ("ln1", "ln2", "final_norm", "q_norm", "k_norm"):
-        return jnp.ones(shape, dtype)
-    std = 0.02 if name in ("embed", "lm_head") else shape[-2] ** -0.5
-    return (jax.random.normal(key, shape, dtype) * jnp.asarray(std, dtype))
-
-
-@functools.partial(jax.jit, static_argnames=("hf_items",))
-def _make(key, hf_items):
+def _build(key, hf_items):
     hf = dict(hf_items)
+    fam = families.of(hf)
     dtype = jnp.dtype(hf["torch_dtype"])
     flat = []
 
@@ -64,18 +42,21 @@ def _make(key, hf_items):
         if isinstance(node, dict):
             return {k: walk(v, f"{path}/{k}") for k, v in node.items()}
         flat.append(path)
-        return _leaf(jax.random.fold_in(key, len(flat)), path, node, dtype)
+        return fam.leaf(jax.random.fold_in(key, len(flat)), path, node,
+                        dtype)
 
-    return walk(shapes(hf), "")
-
-
-_SIZE_KEYS = ("hidden_size", "intermediate_size", "num_attention_heads",
-              "num_key_value_heads", "head_dim", "num_hidden_layers",
-              "vocab_size", "tie_word_embeddings", "torch_dtype")
+    return walk(fam.shapes(hf), "")
 
 
-def make_params(hf: dict, seed: int):
-    """The whole tree for config ``hf`` from ``seed``, on the default
-    device, in the configuration's dtype."""
-    items = tuple((k, hf[k]) for k in _SIZE_KEYS)
-    return _make(seed_key(seed), items)
+def _items(hf: dict) -> tuple:
+    return (("model_type", hf["model_type"]),) + tuple(
+        (k, hf[k]) for k in families.of(hf).SIZE_KEYS)
+
+
+def make_params(hf: dict, seed: int, shardings=None):
+    """The whole tree for config ``hf`` from ``seed``, in the
+    configuration's dtype: on the default device, or made in place in
+    ``shardings`` (a tree of ``NamedSharding``s matching ``shapes``)."""
+    make = jax.jit(functools.partial(_build, hf_items=_items(hf)),
+                   out_shardings=shardings)
+    return make(seed_key(seed))
